@@ -3,9 +3,10 @@
     PYTHONPATH=src python -m benchmarks.cache_smoke
 
 Runs a small `bind_batched` grid dispatch in a child process twice
-against the same fresh `engine.setup_compilation_cache` directory (set
-through the `REPRO_COMPILE_CACHE` env var, so the env path is exercised
-too).  The check is deterministic, not a timing assertion: a warm run
+against one fixed subdirectory of the resolved compilation cache
+(`engine.compilation_cache_dir()`), emptied first.  The children find it
+through `JAX_COMPILATION_CACHE_DIR`, so the environment path is exercised
+too.  The check is deterministic, not a timing assertion: a warm run
 that actually skips compilation reads every executable from the cache
 and writes NO new entries, so any new `jit_*` file in the cache dir
 after the second run means a program was recompiled — that fails the
@@ -17,9 +18,9 @@ host).
 from __future__ import annotations
 
 import os
+import shutil
 import subprocess
 import sys
-import tempfile
 import time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -36,8 +37,7 @@ def _workload() -> None:
     from repro.core import build_topology
     from repro.core.engine import setup_compilation_cache
 
-    cache = setup_compilation_cache()  # from REPRO_COMPILE_CACHE
-    assert cache, "REPRO_COMPILE_CACHE must be set for the smoke child"
+    setup_compilation_cache()  # JAX_COMPILATION_CACHE_DIR, set by the parent
     m, n = 16, 60
     topo = build_topology("ring", m)
     batch, grad_fn, objective = linreg_problem(m, n, spn=16, seed=0)
@@ -63,9 +63,12 @@ def main() -> None:
     if len(sys.argv) > 1 and sys.argv[1] == "child":
         _workload()
         return
-    cache_dir = tempfile.mkdtemp(prefix="repro-cache-smoke-")
+    from repro.core.engine import compilation_cache_dir
+
+    cache_dir = os.path.join(compilation_cache_dir(), "cache_smoke")
+    shutil.rmtree(cache_dir, ignore_errors=True)
     env = dict(os.environ)
-    env["REPRO_COMPILE_CACHE"] = cache_dir
+    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     env["PYTHONPATH"] = os.path.join(REPO, "src") + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
     )

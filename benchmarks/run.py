@@ -888,7 +888,7 @@ def bench_sweep(quick=False):
     # warm dispatch re-traces but swaps the XLA compile for a disk read.
     import shutil
 
-    from repro.core.engine import setup_compilation_cache
+    from repro.core.engine import compilation_cache_dir, setup_compilation_cache
 
     def _grid_dispatch_s():
         t0 = time.perf_counter()
@@ -902,19 +902,16 @@ def bench_sweep(quick=False):
         jax.block_until_ready(h["objective"])
         return time.perf_counter() - t0
 
-    cache_dir = os.path.join(ART, ".jax_cache_race")
+    # a fixed subdirectory of the resolved cache, emptied first, so the
+    # cold dispatch is cold
+    cache_dir = os.path.join(compilation_cache_dir(), "sweep_race")
     shutil.rmtree(cache_dir, ignore_errors=True)
-    prior_dir = jax.config.jax_compilation_cache_dir
-    setup_compilation_cache(cache_dir)
-    cold_s = _grid_dispatch_s()
-    warm_s = _grid_dispatch_s()
-    if prior_dir:
-        setup_compilation_cache(prior_dir)
-    else:
-        jax.config.update("jax_compilation_cache_dir", None)
-        from repro.core.engine import _reset_cache_object
-
-        _reset_cache_object()
+    setup_compilation_cache("sweep_race")
+    try:
+        cold_s = _grid_dispatch_s()
+        warm_s = _grid_dispatch_s()
+    finally:
+        setup_compilation_cache()
     cache_saving = 1.0 - warm_s / max(cold_s, 1e-9)
     cache_table = {
         "cold_s": cold_s, "warm_s": warm_s, "saving": cache_saving,
@@ -1703,22 +1700,10 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None, choices=list(BENCHES))
     ap.add_argument("--quick", action="store_true")
-    ap.add_argument(
-        "--compile-cache", default=os.path.join(ART, ".jax_cache"),
-        metavar="DIR",
-        help="persistent XLA compilation cache (on by default for "
-             "benchmarks; repeat runs skip compilation for unchanged "
-             "programs)",
-    )
-    ap.add_argument(
-        "--no-compile-cache", dest="compile_cache",
-        action="store_const", const=None,
-    )
     args, _ = ap.parse_known_args()
-    if args.compile_cache:
-        from repro.core.engine import setup_compilation_cache
+    from repro.core.engine import setup_compilation_cache
 
-        print(f"# compile cache: {setup_compilation_cache(args.compile_cache)}")
+    print(f"# compile cache: {setup_compilation_cache()}")
     print("name,us_per_call,derived")
     names = [args.only] if args.only else list(BENCHES)
     for name in names:

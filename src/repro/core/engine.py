@@ -39,62 +39,63 @@ from typing import Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.compilation_cache.compilation_cache import reset_cache
 
 __all__ = [
     "make_scan_runner", "run_scan_loop", "run_batched", "history_from",
-    "staleness_hist", "setup_compilation_cache",
+    "staleness_hist", "setup_compilation_cache", "compilation_cache_dir",
 ]
 
 DEFAULT_CHUNK_SIZE = 32
 
 
-def setup_compilation_cache(cache_dir: Optional[str] = None) -> Optional[str]:
-    """Point XLA's persistent compilation cache at `cache_dir`.
+# Where the persistent compilation cache lives when JAX_COMPILATION_CACHE_DIR
+# is unset: a fixed path, because the path is part of what makes a later
+# process find an entry again.
+REPO_CACHE_DIR = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..", ".jax_cache"
+))
+
+
+def compilation_cache_dir() -> str:
+    """The one place the persistent compilation cache is resolved:
+    ``JAX_COMPILATION_CACHE_DIR`` when it is set (JAX reads it into
+    ``jax_compilation_cache_dir`` itself), else ``<repo>/.jax_cache``."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    return env if env else REPO_CACHE_DIR
+
+
+def setup_compilation_cache(subdir: Optional[str] = None) -> str:
+    """Turn XLA's persistent compilation cache on at the resolved directory.
 
     Compile time is the dominant fixed cost of every `bind_batched` grid
     dispatch: a fresh process (or a fresh runner closure) re-traces AND
     re-compiles the whole scan even though the program is byte-identical
     to the last run.  With a persistent cache, tracing still happens but
     the XLA compile is replaced by a disk read keyed on the serialized
-    HLO + compile options — measured 2.9 s → 0.4 s for the sweep-bench
-    grid on CPU.
+    HLO + compile options.
 
-    `cache_dir` defaults to the `REPRO_COMPILE_CACHE` env var; if neither
-    is set this is a no-op returning None (cache disabled).  The two
-    min-threshold knobs are zeroed so even sub-second programs are
-    cached — this repo's workloads are many small scans, not one big XLA
-    program.  The directory fills with `jit_<name>-<fingerprint>` entries
-    (plus `-atime` stamps jax uses for LRU eviction); it is safe to
-    delete wholesale at any time.
+    The directory is `compilation_cache_dir()`.  ``subdir`` names a fixed
+    subdirectory of it, for cold-versus-warm checks that must start from
+    an empty cache of their own.  The two min-threshold knobs are zeroed
+    so even sub-second programs are cached — this repo's workloads are
+    many small scans, not one big XLA program.  The directory fills with
+    `jit_<name>-<fingerprint>` entries (plus `-atime` stamps jax uses for
+    LRU eviction); it is safe to delete wholesale at any time.
 
-    Returns the directory actually configured (for logging).
+    Returns the directory configured (for logging).
     """
-    if cache_dir is None:
-        cache_dir = os.environ.get("REPRO_COMPILE_CACHE")
-    if not cache_dir:
-        return None
+    cache_dir = compilation_cache_dir()
+    if subdir is not None:
+        cache_dir = os.path.join(cache_dir, subdir)
     os.makedirs(cache_dir, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    _reset_cache_object()
-    return cache_dir
-
-
-def _reset_cache_object() -> None:
-    """Make a runtime cache-dir change take effect immediately.
-
-    jax initializes its persistent-cache object lazily ONCE per process;
-    after any compile has touched it, flipping
-    `jax_compilation_cache_dir` is silently ignored until the object is
-    reset.  Without this, `bench_sweep`'s cold-vs-warm race would keep
-    reading the previously configured directory.
-    """
-    try:
-        from jax._src.compilation_cache import reset_cache
-    except ImportError:  # pragma: no cover - future jax relocation
-        return
+    # jax initializes its cache object once per process and ignores a later
+    # directory change until the object is reset
     reset_cache()
+    return cache_dir
 
 
 def history_from(metrics: dict, info: dict, keys: dict) -> dict:

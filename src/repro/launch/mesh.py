@@ -17,27 +17,14 @@ from typing import Optional
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-try:  # AxisType landed after jax 0.4; Auto is the implicit default before it
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 from repro.models.config import ModelConfig
 
 __all__ = [
     "make_production_mesh", "make_logical_mesh", "fsdp_degree",
-    "mesh_axis_kwargs", "HBM_PER_CHIP",
+    "HBM_PER_CHIP",
 ]
-
-
-def mesh_axis_kwargs(n_axes: int) -> dict:
-    """kwargs pinning every mesh axis to Auto on jax versions that have
-    explicit axis types; empty (the same behavior) on older versions."""
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n_axes}
 
 HBM_PER_CHIP = 16e9          # v5e
 PER_CHIP_PARAM_BUDGET = 8e9  # leave headroom for activations/caches
@@ -48,7 +35,7 @@ STATE_MULTIPLier = 3.0       # params + grads + PME aggregate (no opt state)
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **mesh_axis_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def fsdp_degree(cfg: ModelConfig, total_chips: int, model_axis: int = MODEL_AXIS) -> int:
@@ -75,5 +62,5 @@ def make_logical_mesh(
     return Mesh(
         devs.reshape(node, fsdp, MODEL_AXIS),
         ("node", "fsdp", "model"),
-        **mesh_axis_kwargs(3),
+        axis_types=(AxisType.Auto,) * 3,
     )

@@ -339,7 +339,6 @@ def make_parser() -> argparse.ArgumentParser:
                          "is scheduled (membership.check_join_faults)")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=20)
-    ap.add_argument("--compile-cache", default=None, metavar="DIR")
     return ap
 
 
@@ -361,9 +360,8 @@ def _faults_from_args(args):
 
 def main(argv=None):
     args = make_parser().parse_args(argv)
-    cache_dir = engine.setup_compilation_cache(args.compile_cache)
-    if cache_dir:
-        print(f"[serve-train] compilation cache at {cache_dir}", flush=True)
+    cache_dir = engine.setup_compilation_cache()
+    print(f"[serve-train] compilation cache at {cache_dir}", flush=True)
 
     timeline = mb_mod.parse_chaos_spec(args.chaos, args.join_degree)
     events = deque(sorted(

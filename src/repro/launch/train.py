@@ -20,11 +20,12 @@ fresh doubly-stochastic mixing matrix every step inside the scan: links
 fail, nodes drop out (state frozen for the step), stragglers miss the
 exchange window, and only realized edges are charged on the wire.
 
-On a real TPU slice the same driver shards the node-stacked state over the
-(node, fsdp, model) logical mesh; on CPU (tests/examples) everything runs
-on one device.  Substrate exercised: synthetic non-IID corpus ->
-vectorized batch gather -> registry-bound step inside `lax.scan` chunks ->
-metrics log + checkpointing.
+All m nodes live on one device: the node-stacked state holds m full
+copies of the model, so at published widths `--variant full --layers N`
+cuts the depth (never the widths) until m copies fit the device.
+Substrate exercised: synthetic non-IID corpus -> vectorized batch gather
+-> registry-bound step inside `lax.scan` chunks -> metrics log +
+checkpointing.
 """
 from __future__ import annotations
 
@@ -176,8 +177,16 @@ def _faults_from_args(args):
     )
 
 
-def build_everything(args):
+def model_config(args):
+    """The registered config, with `--layers` replacing its depth only."""
     cfg = get_config(args.arch, args.variant)
+    if args.layers is not None:
+        cfg = cfg.replace(n_layers=args.layers)
+    return cfg
+
+
+def build_everything(args):
+    cfg = model_config(args)
     if args.seq and cfg.arch_type == "vlm":
         assert args.seq > cfg.n_patches, "seq must exceed n_patches for vlm"
     m = args.nodes
@@ -239,6 +248,9 @@ def make_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--variant", default="smoke", choices=["smoke", "full"])
+    ap.add_argument("--layers", type=int, default=None, metavar="N",
+                    help="depth cut for --variant full: train N layers at "
+                         "the published widths (default: the full depth)")
     ap.add_argument("--algo", default="pame", choices=list(list_algorithms()))
     ap.add_argument("--mixing", default="sparse", choices=["sparse", "dense"],
                     help="gossip contraction: padded neighbor gather vs dense")
@@ -321,25 +333,35 @@ def make_parser() -> argparse.ArgumentParser:
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--log-every", type=int, default=None,
                     help="log cadence in steps (chunk-aligned; default=chunk)")
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="persistent XLA compilation cache directory "
-                         "(default: $REPRO_COMPILE_CACHE; unset = off). "
-                         "Warm runs skip compilation for identical programs.")
     return ap
 
 
-def main(argv=None) -> None:
-    args = make_parser().parse_args(argv)
+def parse_args(argv=None) -> argparse.Namespace:
+    ap = make_parser()
+    args = ap.parse_args(argv)
+    if args.layers is not None:
+        if args.variant != "full":
+            ap.error("--layers cuts the depth of --variant full only")
+        if args.layers < 1:
+            ap.error("--layers must be at least 1")
+    return args
 
-    cache_dir = engine.setup_compilation_cache(args.compile_cache)
-    if cache_dir:
-        print(f"[train] compilation cache at {cache_dir}", flush=True)
+
+def main(argv=None) -> dict:
+    """Run the CLI; returns the per-step node-mean losses and the
+    parameter count per node (``{"loss": [steps] array, "n_params": int}``;
+    batched runs give ``[steps, lanes]`` losses)."""
+    args = parse_args(argv)
+
+    cache_dir = engine.setup_compilation_cache()
+    print(f"[train] compilation cache at {cache_dir}", flush=True)
 
     cfg, bound, state, make_batch, n_params, params0 = build_everything(args)
     lanes = bound.lanes if args.seeds > 1 else None
     # per-leaf Eq.-(8) accounting when the algorithm partitions over the
     # model pytree (--partition tree); flat formats price sum(sizes)
     wire_per_step = bound.wire_bits_for(params0)
+    del params0  # the state holds the m replicas; free this extra copy
     scen_tag = bound.scenario.name if bound.dynamic else "static"
     if bound.faulty:
         fm = bound.faults
@@ -351,7 +373,8 @@ def main(argv=None) -> None:
     part_tag = f"partition={args.partition} " if args.algo == "pame" else ""
     print(
         f"[train] algo={args.algo} mixing={args.mixing} {part_tag}"
-        f"nodes={args.nodes} scenario={scen_tag} "
+        f"nodes={args.nodes} arch={cfg.name} layers={cfg.n_layers} "
+        f"scenario={scen_tag} "
         + (f"seeds={args.seeds} (batched lanes) " if lanes else "")
         + f"params={n_params/1e6:.2f}M wire_bits/step={wire_per_step:.3e} "
         f"({wire_per_step/8e6:.2f} MB/step network-wide"
@@ -408,6 +431,7 @@ def main(argv=None) -> None:
     k = start
     cum_bits = resumed_bits if resumed_bits is not None else wire_per_step * start
     stale_hist = None
+    losses = []
     next_ckpt = (start // args.ckpt_every + 1) * args.ckpt_every
     while k < args.steps:
         length = min(args.chunk, args.steps - k)
@@ -421,6 +445,7 @@ def main(argv=None) -> None:
         )
         aux = info["aux"]
         k += info["steps_dispatched"]
+        losses.append(np.asarray(metrics["loss_mean"]))
         if "wire_bits" in metrics:  # realized (surviving-edge) accounting
             # batched rows are [steps, L]: report the per-lane average so
             # the log stays comparable with a single-seed run
@@ -477,6 +502,8 @@ def main(argv=None) -> None:
         )
         print(f"[train] staleness histogram (participant-steps): {cells}")
     print("[train] done")
+    return {"loss": np.concatenate(losses) if losses else np.zeros((0,)),
+            "n_params": n_params}
 
 
 if __name__ == "__main__":

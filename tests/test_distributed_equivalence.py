@@ -13,8 +13,7 @@ CODE = textwrap.dedent(
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
-    from jax.sharding import Mesh
-    from repro.launch.mesh import mesh_axis_kwargs
+    from jax.sharding import AxisType, Mesh
     from repro.configs import get_config
     from repro.core.pame import PaMEConfig, pame_init, pame_step, make_topology_arrays
     from repro.core.topology import build_topology
@@ -43,7 +42,7 @@ CODE = textwrap.dedent(
         lambda s, b: pame_step(s, b, grad_fn, arrs, pcfg))(state, batch)
 
     devs = np.array(jax.devices()[:8]).reshape(4, 1, 2)
-    mesh = Mesh(devs, ("node", "fsdp", "model"), **mesh_axis_kwargs(3))
+    mesh = Mesh(devs, ("node", "fsdp", "model"), axis_types=(AxisType.Auto,) * 3)
     state_specs = jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), state)
     state_sh = shd.state_shardings(state_specs, mesh)

@@ -254,33 +254,36 @@ def test_k_start_offsets_step_index_and_termination_window():
 
 
 def test_setup_compilation_cache(tmp_path, monkeypatch):
-    """The cache helper: no-op when unset, env fallback, explicit dir wins,
-    and the configured dir actually receives cache entries on compile."""
+    """The cache resolver: JAX_COMPILATION_CACHE_DIR wins, else the fixed
+    <repo>/.jax_cache; a named subdirectory nests inside the resolved
+    directory; and the configured dir receives cache entries on compile."""
     import os
 
     import jax
 
-    from repro.core.engine import setup_compilation_cache
+    from repro.core import engine
 
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     prior = jax.config.jax_compilation_cache_dir
     try:
-        monkeypatch.delenv("REPRO_COMPILE_CACHE", raising=False)
-        assert setup_compilation_cache() is None  # unset -> disabled
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert engine.compilation_cache_dir() == os.path.join(repo, ".jax_cache")
 
         env_dir = tmp_path / "env_cache"
-        monkeypatch.setenv("REPRO_COMPILE_CACHE", str(env_dir))
-        assert setup_compilation_cache() == str(env_dir)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(env_dir))
+        assert engine.compilation_cache_dir() == str(env_dir)
+        assert engine.setup_compilation_cache() == str(env_dir)
+        assert jax.config.jax_compilation_cache_dir == str(env_dir)
 
-        explicit = tmp_path / "explicit"
-        assert setup_compilation_cache(str(explicit)) == str(explicit)
-        assert jax.config.jax_compilation_cache_dir == str(explicit)
+        sub = engine.setup_compilation_cache("race")
+        assert sub == str(env_dir / "race")
+        assert jax.config.jax_compilation_cache_dir == sub
 
         # a fresh jit closure compiled now must land an entry on disk
         fn = jax.jit(lambda x: x * 2.0 + 1.0)
         jax.block_until_ready(fn(jnp.arange(8.0)))
-        entries = [
-            f for f in os.listdir(explicit) if not f.endswith("-atime")
-        ]
+        entries = [f for f in os.listdir(sub) if not f.endswith("-atime")]
         assert entries, "persistent cache wrote no entries"
     finally:
         jax.config.update("jax_compilation_cache_dir", prior)
+        engine.reset_cache()

@@ -57,22 +57,21 @@ DRYRUN_SNIPPET = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, json
     import numpy as np
-    from jax.sharding import Mesh
+    from jax.sharding import AxisType, Mesh
     import repro.launch.dryrun as dr
     import repro.launch.mesh as lm
-    from repro.launch.mesh import mesh_axis_kwargs
 
     # shrink the production mesh so the test runs fast on 8 fake devices
     def tiny_prod(*, multi_pod=False):
         shape = (2, 2, 2) if multi_pod else (4, 2)
         axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-        return jax.make_mesh(shape, axes, **mesh_axis_kwargs(len(axes)))
+        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
     def tiny_logical(cfg, *, multi_pod=False, production=None):
         prod = production or tiny_prod(multi_pod=multi_pod)
         devs = np.asarray(prod.devices).reshape(-1)
         return Mesh(devs.reshape(2, 2, 2), ("node", "fsdp", "model"),
-                    **mesh_axis_kwargs(3))
+                    axis_types=(AxisType.Auto,) * 3)
 
     lm.make_production_mesh = tiny_prod
     dr.make_production_mesh = tiny_prod

@@ -1,0 +1,95 @@
+"""Compile the Pallas kernels for a TPU v5e at real widths, without a chip.
+
+The TPU compiler is installed with JAX and compiles for a described chip,
+so these tests catch what interpret mode cannot: block shapes the TPU
+lowering refuses, scratch the kernel may not use, programs that do not
+fit.  Nothing runs; each test checks that the kernel lowered to a TPU
+custom call.  The topology is described in a fixture, never at import, so
+that under several test workers only the one running this file loads the
+TPU library.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental.compilation_cache.compilation_cache import reset_cache
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One v5e chip of a described 2x2 slice; the persistent compilation
+    cache is off meanwhile (a TPU entry written here cannot be read back
+    without a chip)."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    prior = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prior)
+    reset_cache()
+
+
+def _compile(fn, *shapes):
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def test_pme_average_compiles_for_v5e(one_chip):
+    """m = 4 nodes over one stablelm-1.6b MLP matrix (2048 x 5632), bf16:
+    the exact-mode leaves PaME routes through this kernel on a TPU."""
+    from repro.kernels.pme_average.kernel import pme_average_pallas
+
+    m, n = 4, 2048 * 5632
+    w = jax.ShapeDtypeStruct((m, n), jnp.bfloat16, sharding=one_chip)
+    a = jax.ShapeDtypeStruct((m, m), jnp.bfloat16, sharding=one_chip)
+    _compile(pme_average_pallas, w, w, a)
+
+
+def test_gossip_compiles_for_v5e(one_chip):
+    """m = 32 nodes at degree 8, PME's two terms (payload and counts) on
+    one shared weight table, over one stablelm-1.6b MLP matrix in f32."""
+    from repro.kernels.gossip.kernel import gossip_gather_pallas
+
+    m, k, n = 32, 8, 2048 * 5632
+    nbrs = jax.ShapeDtypeStruct((m, k), jnp.int32, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((m, k), jnp.float32, sharding=one_chip)
+    x = jax.ShapeDtypeStruct((m, n), jnp.float32, sharding=one_chip)
+    _compile(lambda nb, ww, a, b: gossip_gather_pallas(nb, (ww,), (a, b), (0, 0)),
+             nbrs, w, x, x)
+
+
+def test_flash_attention_compiles_for_v5e(one_chip):
+    """stablelm-1.6b attention: 32 heads of 64 at seq 2048, bf16."""
+    from repro.kernels.flash_attention.kernel import flash_attention_pallas
+
+    q = jax.ShapeDtypeStruct((1, 2048, 32, 64), jnp.bfloat16, sharding=one_chip)
+    _compile(flash_attention_pallas, q, q, q)
+
+
+def test_ssd_scan_compiles_for_v5e(one_chip):
+    """mamba2-1.3b's SSD: 64 heads of 64, one B/C group of state 128,
+    chunk 128, seq 2048."""
+    from repro.kernels.ssd_scan.kernel import ssd_intra_chunk_pallas
+
+    b, nc, l, h, p, g, n = 1, 16, 128, 64, 64, 1, 128
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    _compile(
+        lambda x, dt, cum, bb, cc: ssd_intra_chunk_pallas(x, dt, cum, bb, cc, h // g),
+        sds((b, nc, l, h, p), jnp.bfloat16), sds((b, nc, l, h), jnp.float32),
+        sds((b, nc, l, h), jnp.float32), sds((b, nc, l, g, n), jnp.bfloat16),
+        sds((b, nc, l, g, n), jnp.bfloat16),
+    )

@@ -48,28 +48,32 @@ def _consensus(params):
 
 
 # Captured at the commit BEFORE partition="tree" existed (same problem,
-# same seeds).  Sampled at steps [0, 3, 7, 11] of a 12-step run.
+# same seeds), then re-pinned under jax 0.9.0 with the code unchanged: that
+# release turned `jax_threefry_partitionable` on by default, which changes
+# every random stream (masks, neighbor selection), so the old curves moved
+# from step 3 on (loss 14.00 -> 14.95).  The flat path itself did not change.
+# Sampled at steps [0, 3, 7, 11] of a 12-step run.
 FLAT_PINS = {
     ("bernoulli", "sparse"): (
-        [87.24075317382812, 14.000433921813965, 1.6537327766418457,
-         0.21160268783569336],
-        [6.774550437927246, 38.692359924316406, 61.057186126708984,
-         69.43019104003906], 120640),
+        [87.24075317382812, 14.945980072021484, 1.6438064575195312,
+         0.20970168709754944],
+        [6.774549961090088, 37.67254638671875, 60.93923568725586,
+         69.51057434082031], 120640),
     ("bernoulli", "dense"): (
-        [87.24075317382812, 14.000433921813965, 1.6537327766418457,
-         0.21160268783569336],
-        [6.774550437927246, 38.692359924316406, 61.057186126708984,
-         69.43019104003906], 120640),
+        [87.24075317382812, 14.945980072021484, 1.6438064575195312,
+         0.20970168709754944],
+        [6.774549961090088, 37.672542572021484, 60.939231872558594,
+         69.51057434082031], 120640),
     ("exact", "sparse"): (
-        [87.24075317382812, 13.98813533782959, 1.582690715789795,
-         0.19989681243896484],
-        [6.774550437927246, 38.752159118652344, 61.375789642333984,
-         69.67984008789062], 120640),
+        [87.24075317382812, 14.430669784545898, 1.5629053115844727,
+         0.16956445574760437],
+        [6.774549961090088, 38.13227081298828, 61.316650390625,
+         70.04749298095703], 120640),
     ("exact", "dense"): (
-        [87.24075317382812, 13.98813533782959, 1.582690715789795,
-         0.19989681243896484],
-        [6.774550437927246, 38.752159118652344, 61.375789642333984,
-         69.67984008789062], 120640),
+        [87.24075317382812, 14.430669784545898, 1.5629053115844727,
+         0.16956445574760437],
+        [6.774549961090088, 38.132266998291016, 61.316650390625,
+         70.04749298095703], 120640),
 }
 
 
