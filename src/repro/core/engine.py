@@ -30,6 +30,15 @@ Batches are prefetched per chunk on the host (`batch_fn(k)` for each step
 of the chunk).  When `batch_fn` returns the *same object* every step (the
 common full-batch case) the chunk is compiled with the batch closed over
 as a single non-scanned operand instead of stacking `chunk_size` copies.
+
+What a chunk does is named for the profiler: on the host, each chunk is an
+``engine.chunk`` step span (``step_num`` = its first step) holding
+``engine.batches``, ``engine.stack``, ``engine.dispatch`` and
+``engine.readback``; on the device, the scan body's termination window,
+freeze selects and per-step outputs run under the ``engine.carry`` scope.
+Each new chunk program records the ``CHUNK_BUILD_EVENT`` monitoring event,
+and the runner's ``optimized_hlo()`` gives the compiled text of the chunk
+programs it has run, whose instruction names a device trace uses.
 """
 from __future__ import annotations
 
@@ -47,6 +56,10 @@ __all__ = [
 ]
 
 DEFAULT_CHUNK_SIZE = 32
+
+# jax.monitoring event recorded each time a runner builds a new chunk
+# program (a new (length, const_batch) pair), with both as keyword values
+CHUNK_BUILD_EVENT = "/repro/engine/chunk_build"
 
 
 # Where the persistent compilation cache lives when JAX_COMPILATION_CACHE_DIR
@@ -143,6 +156,19 @@ def _tree_select(pred: jax.Array, on_true: object, on_false: object) -> object:
     )
 
 
+def _abstract(x) -> jax.ShapeDtypeStruct:
+    """What jit sees of one argument: shape, dtype, weak type and, for a
+    device array committed to its devices, its placement (an uncommitted
+    one is placed by jit, and a placement given here would change the
+    lowered program)."""
+    aval = jax.typeof(x)
+    committed = isinstance(x, jax.Array) and x.committed
+    return jax.ShapeDtypeStruct(
+        aval.shape, aval.dtype, weak_type=aval.weak_type,
+        sharding=x.sharding if committed else None,
+    )
+
+
 def make_scan_runner(
     step_fn: Callable,  # (state, batch) -> (state, metrics dict of scalars)
     *,
@@ -195,6 +221,12 @@ def make_scan_runner(
     and the bounded-staleness parameter ring ride it across steps with no
     host round-trips — and is frozen by the same termination select as the
     state.
+
+    The returned ``run`` also carries two read-only accessors over the
+    chunk programs it has run, keyed like its cache by ``(length,
+    const_batch)``: ``run.chunk_programs()`` gives each jitted chunk with
+    the abstract arguments (shapes, dtypes, placement) of its first call,
+    and ``run.optimized_hlo()`` their optimized HLO text.
     """
 
     def _scan_body(carry: _Carry, k: jax.Array, k_rel: jax.Array, batch: object):
@@ -206,42 +238,47 @@ def make_scan_runner(
         else:
             new_state, metrics = step_fn(*step_args)
             new_aux = carry.aux
-        if objective_fn is not None:
-            # node axis is 0 for single runs, 1 behind the lane axis
-            mean_params = jax.tree_util.tree_map(
-                lambda x: x.mean(axis=0 if lanes is None else 1),
-                params_of(new_state),
-            )
-            obj_fn = objective_fn if lanes is None else jax.vmap(objective_fn)
-            obj = obj_fn(mean_params).astype(jnp.float32)  # [] or [L]
-            win = jnp.concatenate([carry.win[..., 1:], obj[..., None]], -1)
-            # guard on steps into *this run* (k_rel), not the global index:
-            # each run() starts a fresh zero window, and a k_start > 0 run
-            # must still fill all three slots before the rule can fire.
-            trigger = (k_rel >= 2) & (jnp.std(win, axis=-1) < tol_std)
-        else:
-            obj = None
-            win = carry.win
-            trigger = jnp.zeros((() if lanes is None else (lanes,)), bool)
-        # A step that runs *after* the rule fired is a no-op: keep the frozen
-        # state so the returned state is exactly the triggering step's (per
-        # lane, when batched).
-        frozen = carry.done
-        out_state = _tree_select(frozen, carry.state, new_state)
-        out_aux = _tree_select(frozen, carry.aux, new_aux)
-        out_win = _sel(frozen, carry.win, win)
-        done = carry.done | trigger
-        ys = dict(metrics)
-        if obj is not None:
-            ys["objective"] = obj
-        ys["_stopped"] = done
-        return _Carry(out_state, done, out_win, out_aux), ys
+        with jax.named_scope("engine.carry"):
+            if objective_fn is not None:
+                # node axis is 0 for single runs, 1 behind the lane axis
+                mean_params = jax.tree_util.tree_map(
+                    lambda x: x.mean(axis=0 if lanes is None else 1),
+                    params_of(new_state),
+                )
+                obj_fn = objective_fn if lanes is None else jax.vmap(objective_fn)
+                obj = obj_fn(mean_params).astype(jnp.float32)  # [] or [L]
+                win = jnp.concatenate([carry.win[..., 1:], obj[..., None]], -1)
+                # guard on steps into *this run* (k_rel), not the global index:
+                # each run() starts a fresh zero window, and a k_start > 0 run
+                # must still fill all three slots before the rule can fire.
+                trigger = (k_rel >= 2) & (jnp.std(win, axis=-1) < tol_std)
+            else:
+                obj = None
+                win = carry.win
+                trigger = jnp.zeros((() if lanes is None else (lanes,)), bool)
+            # A step that runs *after* the rule fired is a no-op: keep the frozen
+            # state so the returned state is exactly the triggering step's (per
+            # lane, when batched).
+            frozen = carry.done
+            out_state = _tree_select(frozen, carry.state, new_state)
+            out_aux = _tree_select(frozen, carry.aux, new_aux)
+            out_win = _sel(frozen, carry.win, win)
+            done = carry.done | trigger
+            ys = dict(metrics)
+            if obj is not None:
+                ys["objective"] = obj
+            ys["_stopped"] = done
+            return _Carry(out_state, done, out_win, out_aux), ys
 
     compiled: dict = {}  # (length, const_batch) -> jitted chunk fn
+    abstract: dict = {}  # (length, const_batch) -> its first call's arguments
 
     def _chunk_fn(length: int, const_batch: bool):
         key = (length, const_batch)
         if key not in compiled:
+            jax.monitoring.record_event(
+                CHUNK_BUILD_EVENT, length=length, const_batch=int(const_batch)
+            )
 
             def chunk(carry, batch, k0, r0):
                 ks = k0 + jnp.arange(length)
@@ -305,39 +342,55 @@ def make_scan_runner(
         ys_chunks = []
         k0 = k_start
         end = k_start + num_steps
-        while k0 < end:
+        last = num_steps <= 0
+        # Host spans on the profiler's clock: every span of one chunk lies
+        # inside its engine.chunk span, whose step_num is the chunk's first
+        # step.  They are cheap while no profiler session is open.
+        while not last:
             length = min(chunk_size, end - k0)
-            batches = [batch_fn(k) for k in range(k0, k0 + length)]
-            leaves0, treedef0 = jax.tree_util.tree_flatten(batches[0])
-            const = all(_same_batch(b, batches[0]) for b in batches[1:])
-            if const:
-                batch = batches[0]
-            else:
-                batch = jax.tree_util.tree_map(
-                    lambda *xs: jnp.stack(xs), *batches
-                )
-            carry, ys = _chunk_fn(length, const)(
-                carry, batch, jnp.asarray(k0, jnp.int32),
-                jnp.asarray(k0 - k_start, jnp.int32),
-            )
-            ys_chunks.append(ys)
-            k0 += length
-            # one scalar sync per chunk boundary — the only mid-run readback
-            # (batched runs stop once *every* lane's rule has fired)
-            if objective_fn is not None and bool(
-                jax.device_get(carry.done.all())
-            ):
-                break
+            with jax.profiler.StepTraceAnnotation("engine.chunk", step_num=k0):
+                with jax.profiler.TraceAnnotation("engine.batches"):
+                    batches = [batch_fn(k) for k in range(k0, k0 + length)]
+                with jax.profiler.TraceAnnotation("engine.stack"):
+                    leaves0, treedef0 = jax.tree_util.tree_flatten(batches[0])
+                    const = all(_same_batch(b, batches[0]) for b in batches[1:])
+                    if const:
+                        batch = batches[0]
+                    else:
+                        batch = jax.tree_util.tree_map(
+                            lambda *xs: jnp.stack(xs), *batches
+                        )
+                with jax.profiler.TraceAnnotation("engine.dispatch"):
+                    args = (
+                        carry, batch, jnp.asarray(k0, jnp.int32),
+                        jnp.asarray(k0 - k_start, jnp.int32),
+                    )
+                    fn = _chunk_fn(length, const)
+                    if (length, const) not in abstract:
+                        abstract[(length, const)] = jax.tree_util.tree_map(
+                            _abstract, args
+                        )
+                    carry, ys = fn(*args)
+                ys_chunks.append(ys)
+                k0 += length
+                last = k0 >= end
+                if objective_fn is not None or last:
+                    with jax.profiler.TraceAnnotation("engine.readback"):
+                        # one scalar sync per chunk boundary — the only
+                        # mid-run readback (batched runs stop once *every*
+                        # lane's rule has fired)
+                        if objective_fn is not None:
+                            last |= bool(jax.device_get(carry.done.all()))
+                        if last:  # single bulk readback of all metrics
+                            host = jax.device_get(jax.tree_util.tree_map(
+                                lambda *xs: jnp.concatenate(xs), *ys_chunks
+                            ))
         if not ys_chunks:
             zero_steps = 0 if lanes is None else np.zeros(lanes, np.int64)
             return carry.state, {}, {
                 "steps_run": zero_steps, "steps_dispatched": 0,
                 "aux": carry.aux,
             }
-        stacked = jax.tree_util.tree_map(
-            lambda *xs: jnp.concatenate(xs), *ys_chunks
-        )
-        host = jax.device_get(stacked)  # single bulk readback of all metrics
         stopped = host.pop("_stopped")  # [steps] or [steps, L]
         if lanes is None:
             steps_run = (
@@ -358,6 +411,22 @@ def make_scan_runner(
             "aux": carry.aux,
         }
 
+    def chunk_programs() -> dict:
+        """(length, const_batch) -> (jitted chunk, the abstract arguments of
+        its first call) for every chunk program this runner has run."""
+        return {key: (compiled[key], args) for key, args in abstract.items()}
+
+    def optimized_hlo() -> dict:
+        """(length, const_batch) -> the optimized HLO text of each chunk
+        program this runner has run.  Lowers and compiles on the call (an
+        executable the persistent compilation cache holds is read back)."""
+        return {
+            key: fn.lower(*args).compile().as_text()
+            for key, (fn, args) in chunk_programs().items()
+        }
+
+    run.chunk_programs = chunk_programs
+    run.optimized_hlo = optimized_hlo
     return run
 
 

@@ -167,10 +167,7 @@ def pame_step(
     # arbitrary asymmetric loss, with the lambda=0 fill as the limit case.
 ) -> Tuple[PaMEState, dict]:
     m = topo.nbrs.shape[0]
-    k_sel, k_mask, k_data = (
-        jax.random.fold_in(state.key, state.step * 3 + i) for i in range(3)
-    )
-
+    sparse = cfg.exchange == "dense" and cfg.mixing == "sparse"
     if cfg.partition == "tree":
         # tree-partitioned exchange: each leaf is its own message segment
         # with its own rate; a float keeps the flat code path bit-identical
@@ -178,45 +175,55 @@ def pame_step(
         rate = pme.leaf_rates(num_leaves, cfg.p, cfg.p_leaf)
     else:
         rate = cfg.p
+    if not sparse and delivered is not None:
+        raise NotImplementedError(
+            "message-level delivery masks need mixing='sparse' "
+            "(padded selection); the dense selection matrix has no "
+            "per-slot delivery channel"
+        )
+    if cfg.exchange in ("compressed", "compressed_q8") and self_params is not None:
+        raise NotImplementedError(
+            "self_params (message-only delay) is not supported on "
+            "the compressed exchange path"
+        )
 
-    comm_mask = (state.step % topo.kappa) == 0  # k in K_i
-    survivors = None
-    if realization is not None:
-        # offline / straggling receivers skip the exchange entirely; the
-        # sender side is filtered through the realized edge set below.
-        comm_mask = comm_mask & realization.participating
-        survivors = realization.edge_alive
-    if cfg.exchange == "dense" and cfg.mixing == "sparse":
-        # padded neighbor-exchange: never materialise the [m, m] selection
-        # matrix; gather over max_degree slots instead (same PRNG draws).
-        sel = pme.sample_neighbor_selection_padded(
-            k_sel, topo.nbrs, topo.valid, topo.t, comm_mask, survivors=survivors
+    # The round's work is named by scope (pame.select, pame.exchange,
+    # pame.local_step, pame.update, pame.metrics) so that a device trace can
+    # be split by layer; scopes are metadata and leave the program as it is.
+    with jax.named_scope("pame.select"):
+        k_sel, k_mask, k_data = (
+            jax.random.fold_in(state.key, state.step * 3 + i) for i in range(3)
         )
-        n_messages = jnp.sum(sel.astype(jnp.int32))
-        sel_recv = sel if delivered is None else sel & delivered
-        v_bar = pme.pme_average_pytree_padded(
-            k_mask, state.params, topo.nbrs, sel_recv, rate,
-            mode=cfg.mask_mode, pad=~topo.valid, self_params=self_params,
-        )
-    else:
-        if delivered is not None:
-            raise NotImplementedError(
-                "message-level delivery masks need mixing='sparse' "
-                "(padded selection); the dense selection matrix has no "
-                "per-slot delivery channel"
+        comm_mask = (state.step % topo.kappa) == 0  # k in K_i
+        survivors = None
+        if realization is not None:
+            # offline / straggling receivers skip the exchange entirely; the
+            # sender side is filtered through the realized edge set below.
+            comm_mask = comm_mask & realization.participating
+            survivors = realization.edge_alive
+        if sparse:
+            # padded neighbor-exchange: never materialise the [m, m] selection
+            # matrix; gather over max_degree slots instead (same PRNG draws).
+            sel = pme.sample_neighbor_selection_padded(
+                k_sel, topo.nbrs, topo.valid, topo.t, comm_mask, survivors=survivors
             )
-        a = pme.sample_neighbor_selection(
-            k_sel, topo.nbrs, topo.valid, topo.t, comm_mask, survivors=survivors
-        )
-        n_messages = jnp.sum(a).astype(jnp.int32)
-        if cfg.exchange in ("compressed", "compressed_q8"):
+            n_messages = jnp.sum(sel.astype(jnp.int32))
+            sel_recv = sel if delivered is None else sel & delivered
+        else:
+            a = pme.sample_neighbor_selection(
+                k_sel, topo.nbrs, topo.valid, topo.t, comm_mask, survivors=survivors
+            )
+            n_messages = jnp.sum(a).astype(jnp.int32)
+
+    with jax.named_scope("pame.exchange"):
+        if sparse:
+            v_bar = pme.pme_average_pytree_padded(
+                k_mask, state.params, topo.nbrs, sel_recv, rate,
+                mode=cfg.mask_mode, pad=~topo.valid, self_params=self_params,
+            )
+        elif cfg.exchange in ("compressed", "compressed_q8"):
             from repro.core import gossip
 
-            if self_params is not None:
-                raise NotImplementedError(
-                    "self_params (message-only delay) is not supported on "
-                    "the compressed exchange path"
-                )
             v_bar = gossip.compressed_pme_average_pytree(
                 k_mask, state.params, a, cfg.p, shardings=param_shardings,
                 quantize_bits=8 if cfg.exchange == "compressed_q8" else 0,
@@ -226,54 +233,57 @@ def pame_step(
                 k_mask, state.params, a, rate, mode=cfg.mask_mode,
                 self_params=self_params,
             )
-    if param_shardings is not None:
-        v_bar = jax.lax.with_sharding_constraint(v_bar, param_shardings)
+        if param_shardings is not None:
+            v_bar = jax.lax.with_sharding_constraint(v_bar, param_shardings)
 
-    node_keys = jax.random.split(k_data, m)
-    losses, grads = jax.vmap(grad_fn)(v_bar, batch, node_keys)
+    with jax.named_scope("pame.local_step"):
+        node_keys = jax.random.split(k_data, m)
+        losses, grads = jax.vmap(grad_fn)(v_bar, batch, node_keys)
 
-    stepsize = 1.0 / (state.sigma * topo.t.astype(jnp.float32))
-    new_params = _tree_scale_sub(v_bar, grads, stepsize)
+    with jax.named_scope("pame.update"):
+        stepsize = 1.0 / (state.sigma * topo.t.astype(jnp.float32))
+        new_params = _tree_scale_sub(v_bar, grads, stepsize)
+        new_state = PaMEState(
+            params=new_params,
+            sigma=state.sigma * cfg.gamma,
+            step=state.step + 1,
+            key=state.key,
+        )
 
-    # consensus error ||W - Pi||_F^2 (metric of Lemma 6)
-    def _cons(leaf):
-        mean = leaf.mean(axis=0, keepdims=True)
-        return jnp.sum((leaf - mean) ** 2)
+    with jax.named_scope("pame.metrics"):
+        # consensus error ||W - Pi||_F^2 (metric of Lemma 6)
+        def _cons(leaf):
+            mean = leaf.mean(axis=0, keepdims=True)
+            return jnp.sum((leaf - mean) ** 2)
 
-    consensus = sum(jax.tree_util.tree_leaves(
-        jax.tree_util.tree_map(_cons, new_params)
-    ))
-
-    new_state = PaMEState(
-        params=new_params,
-        sigma=state.sigma * cfg.gamma,
-        step=state.step + 1,
-        key=state.key,
-    )
-    metrics = {
-        "loss_mean": jnp.mean(losses),
-        "consensus": consensus,
-        "comm_nodes": jnp.sum(comm_mask.astype(jnp.int32)),
-        "sigma_mean": jnp.mean(new_state.sigma),
-    }
-    if realization is not None:
-        # realized Eq.-(8) accounting: each selected surviving neighbor
-        # transmits one sparse message, in the int8 wire format when
-        # exchange="compressed_q8".  Flat partition prices one concatenated
-        # vector of s = round(p·n_total) coordinates; tree partition sums
-        # the per-leaf segments (their own s_leaf + occupancy pattern each).
-        sizes = [
-            int(np.prod(leaf.shape[1:]))
-            for leaf in jax.tree_util.tree_leaves(state.params)
-        ]
-        value_bits = 8 if cfg.exchange == "compressed_q8" else 64
-        if cfg.partition == "tree":
-            bits = pme.tree_message_bits(sizes, rate, value_bits)
-        else:
-            n_total = sum(sizes)
-            s = max(1, int(round(cfg.p * n_total)))
-            bits = pme.message_bits(s, n_total, value_bits)
-        metrics["wire_bits"] = n_messages.astype(jnp.float32) * float(bits)
+        consensus = sum(jax.tree_util.tree_leaves(
+            jax.tree_util.tree_map(_cons, new_params)
+        ))
+        metrics = {
+            "loss_mean": jnp.mean(losses),
+            "consensus": consensus,
+            "comm_nodes": jnp.sum(comm_mask.astype(jnp.int32)),
+            "sigma_mean": jnp.mean(new_state.sigma),
+        }
+        if realization is not None:
+            # realized Eq.-(8) accounting: each selected surviving neighbor
+            # transmits one sparse message, in the int8 wire format when
+            # exchange="compressed_q8".  Flat partition prices one
+            # concatenated vector of s = round(p·n_total) coordinates; tree
+            # partition sums the per-leaf segments (their own s_leaf +
+            # occupancy pattern each).
+            sizes = [
+                int(np.prod(leaf.shape[1:]))
+                for leaf in jax.tree_util.tree_leaves(state.params)
+            ]
+            value_bits = 8 if cfg.exchange == "compressed_q8" else 64
+            if cfg.partition == "tree":
+                bits = pme.tree_message_bits(sizes, rate, value_bits)
+            else:
+                n_total = sum(sizes)
+                s = max(1, int(round(cfg.p * n_total)))
+                bits = pme.message_bits(s, n_total, value_bits)
+            metrics["wire_bits"] = n_messages.astype(jnp.float32) * float(bits)
     return new_state, metrics
 
 

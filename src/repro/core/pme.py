@@ -200,72 +200,84 @@ def pme_average_pytree(
     """
     leaves, treedef = jax.tree_util.tree_flatten(params)
     self_leaves = (
-        leaves if self_params is None
+        [None] * len(leaves) if self_params is None
         else jax.tree_util.tree_flatten(self_params)[0]
     )
     m = leaves[0].shape[0]
     per_leaf = isinstance(p, (tuple, list))
     out = []
     for idx, leaf in enumerate(leaves):
-        lkey = jax.random.fold_in(key, idx)
         own = self_leaves[idx]
         p_i = p[idx] if per_leaf else p
-        if mode == "exact":
-            flat = leaf.reshape(m, -1)
-            n = flat.shape[1]
-            s = max(1, int(round(p_i * n)))
-            masks = sample_coordinate_masks(lkey, m, n, s, mode="exact")
-            from repro.core.mixing import default_impl
-
-            if self_params is None and (
-                default_impl() == "pallas"
-                or (
-                    flat.size >= _KERNEL_MIN_ELEMS
-                    and jax.default_backend() != "cpu"
-                )
-            ):
-                # hot path: fused Pallas kernel (1 HBM read + 1 write of the
-                # [m, n] operand).  By size/backend gate, tiny leaves stay on
-                # the einsum path — kernel launch overhead dominates — and so
-                # does CPU, where the kernel only exists in (much slower)
-                # interpret mode.  REPRO_GOSSIP_IMPL="pallas" overrides both
-                # gates so the whole dense-exchange path runs through the
-                # kernel (interpret on CPU) alongside the fused gossip
-                # contraction.  (The kernel computes the fallback from `w`
-                # internally, so a self-view override routes through the
-                # einsum instead.)
-                from repro.kernels.pme_average.ops import (
-                    pme_average as pme_average_fused,
-                )
-
-                avg = pme_average_fused(flat, masks, a)
-            elif self_params is None:
-                # positional-only call: drop-in average variants (e.g. the
-                # naive_average ablation) need not know about `own`
-                avg = pme_average(flat, masks, a)
+        with jax.named_scope("pme.mask"):
+            lkey = jax.random.fold_in(key, idx)
+            if mode == "exact":
+                flat = leaf.reshape(m, -1)
+                n = flat.shape[1]
+                s = max(1, int(round(p_i * n)))
+                masks = sample_coordinate_masks(lkey, m, n, s, mode="exact")
             else:
-                avg = pme_average(flat, masks, a, own=own.reshape(m, -1))
-            out.append(avg.reshape(leaf.shape))
-        else:
-            # No reshape: keep the leaf's trailing structure (and thus its
-            # tensor sharding) intact; only the node axis is contracted.
-            # Operands stay in the leaf dtype (bf16 at model scale) with f32
-            # accumulation — counts <= m are exactly representable.
-            masks = jax.random.bernoulli(lkey, p_i, leaf.shape)
-            mask_t = masks.astype(leaf.dtype)
-            a_t = a.astype(leaf.dtype)
-            agg = jnp.einsum(
-                "j...,ji->i...", leaf * mask_t, a_t,
-                preferred_element_type=jnp.float32,
-            )
-            cnt = jnp.einsum(
-                "j...,ji->i...", mask_t, a_t, preferred_element_type=jnp.float32
-            )
-            avg = jnp.where(
-                cnt > 0, (agg / jnp.maximum(cnt, 1.0)).astype(leaf.dtype), own
-            )
-            out.append(avg)
+                masks = jax.random.bernoulli(lkey, p_i, leaf.shape)
+        with jax.named_scope("pme.average"):
+            out.append(_average_leaf(leaf, masks, a, own, mode))
     return jax.tree_util.tree_unflatten(treedef, out)
+
+
+def _average_leaf(leaf, masks, a, own, mode: str):
+    """One leaf of `pme_average_pytree`: the count-weighted average of the
+    masked senders, with the receiver's `own` view (the leaf itself when
+    None) where no sender covers a coordinate."""
+    m = leaf.shape[0]
+    if mode == "exact":
+        flat = leaf.reshape(m, -1)
+        from repro.core.mixing import default_impl
+
+        if own is None and (
+            default_impl() == "pallas"
+            or (
+                flat.size >= _KERNEL_MIN_ELEMS
+                and jax.default_backend() != "cpu"
+            )
+        ):
+            # hot path: fused Pallas kernel (1 HBM read + 1 write of the
+            # [m, n] operand).  By size/backend gate, tiny leaves stay on
+            # the einsum path — kernel launch overhead dominates — and so
+            # does CPU, where the kernel only exists in (much slower)
+            # interpret mode.  REPRO_GOSSIP_IMPL="pallas" overrides both
+            # gates so the whole dense-exchange path runs through the
+            # kernel (interpret on CPU) alongside the fused gossip
+            # contraction.  (The kernel computes the fallback from `w`
+            # internally, so a self-view override routes through the
+            # einsum instead.)
+            from repro.kernels.pme_average.ops import (
+                pme_average as pme_average_fused,
+            )
+
+            avg = pme_average_fused(flat, masks, a)
+        elif own is None:
+            # positional-only call: drop-in average variants (e.g. the
+            # naive_average ablation) need not know about `own`
+            avg = pme_average(flat, masks, a)
+        else:
+            avg = pme_average(flat, masks, a, own=own.reshape(m, -1))
+        return avg.reshape(leaf.shape)
+    # No reshape: keep the leaf's trailing structure (and thus its
+    # tensor sharding) intact; only the node axis is contracted.
+    # Operands stay in the leaf dtype (bf16 at model scale) with f32
+    # accumulation — counts <= m are exactly representable.
+    mask_t = masks.astype(leaf.dtype)
+    a_t = a.astype(leaf.dtype)
+    agg = jnp.einsum(
+        "j...,ji->i...", leaf * mask_t, a_t,
+        preferred_element_type=jnp.float32,
+    )
+    cnt = jnp.einsum(
+        "j...,ji->i...", mask_t, a_t, preferred_element_type=jnp.float32
+    )
+    return jnp.where(
+        cnt > 0, (agg / jnp.maximum(cnt, 1.0)).astype(leaf.dtype),
+        leaf if own is None else own,
+    )
 
 
 def pme_average_pytree_padded(
@@ -305,32 +317,35 @@ def pme_average_pytree_padded(
     per_leaf = isinstance(p, (tuple, list))
     out = []
     for idx, leaf in enumerate(leaves):
-        lkey = jax.random.fold_in(key, idx)
         own = self_leaves[idx]
         shape = leaf.shape
         p_i = p[idx] if per_leaf else p
-        if mode == "exact":
-            flat = leaf.reshape(m, -1)
-            n = flat.shape[1]
-            s = max(1, int(round(p_i * n)))
-            masks = sample_coordinate_masks(lkey, m, n, s, mode="exact")
-            payload = jnp.where(masks, flat, 0.0)
+        with jax.named_scope("pme.mask"):
+            lkey = jax.random.fold_in(key, idx)
+            if mode == "exact":
+                flat = leaf.reshape(m, -1)
+                n = flat.shape[1]
+                s = max(1, int(round(p_i * n)))
+                masks = sample_coordinate_masks(lkey, m, n, s, mode="exact")
+            else:
+                flat = leaf
+                masks = jax.random.bernoulli(lkey, p_i, shape)
+        with jax.named_scope("pme.average"):
+            if mode == "exact":
+                payload = jnp.where(masks, flat, 0.0)
+            else:
+                payload = flat * masks.astype(flat.dtype)
             mask_f = masks.astype(jnp.float32)
-        else:
-            masks = jax.random.bernoulli(lkey, p_i, shape)
-            flat = leaf
-            payload = flat * masks.astype(flat.dtype)
-            mask_f = masks.astype(jnp.float32)
-        agg, cnt = gather_terms(
-            nbrs,
-            [(sel_f, payload.astype(jnp.float32)), (sel_f, mask_f)],
-            pad=pad, impl=impl,
-        )
-        fallback = flat if self_params is None else own.reshape(flat.shape)
-        avg = jnp.where(
-            cnt > 0, (agg / jnp.maximum(cnt, 1.0)).astype(flat.dtype), fallback
-        )
-        out.append(avg.reshape(shape))
+            agg, cnt = gather_terms(
+                nbrs,
+                [(sel_f, payload.astype(jnp.float32)), (sel_f, mask_f)],
+                pad=pad, impl=impl,
+            )
+            fallback = flat if self_params is None else own.reshape(flat.shape)
+            avg = jnp.where(
+                cnt > 0, (agg / jnp.maximum(cnt, 1.0)).astype(flat.dtype), fallback
+            )
+            out.append(avg.reshape(shape))
     return jax.tree_util.tree_unflatten(treedef, out)
 
 

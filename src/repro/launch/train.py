@@ -426,8 +426,34 @@ def main(argv=None) -> dict:
         bound.step, chunk_size=args.chunk, step_takes_index=bound.dynamic,
         carries_aux=carries_aux, lanes=lanes,
     )
+    # chunk programs the engine builds (a first chunk, a shorter last one)
+    # are named in the next log line: their wall time holds the compile
+    built = []
+
+    def on_event(event, **kwargs):
+        if event == engine.CHUNK_BUILD_EVENT:
+            built.append(kwargs["length"])
+
+    jax.monitoring.register_event_listener(on_event)
+    try:
+        losses = _train_loop(
+            args, runner, state, aux, make_batch, start, built,
+            wire_per_step=wire_per_step, resumed_bits=resumed_bits,
+            lanes=lanes, carries_aux=carries_aux,
+        )
+    finally:
+        jax.monitoring.unregister_event_listener(on_event)
+    print("[train] done")
+    return {"loss": np.concatenate(losses) if losses else np.zeros((0,)),
+            "n_params": n_params}
+
+
+def _train_loop(args, runner, state, aux, make_batch, start, built, *,
+                wire_per_step, resumed_bits, lanes, carries_aux) -> list:
+    """Chunks from step ``start`` to ``args.steps``: log lines, wire bits,
+    checkpoints; returns the per-chunk node-mean losses.  ``built`` holds
+    the lengths of the chunk programs built since the last log line."""
     log_every = max(args.log_every or args.chunk, 1)
-    t0 = time.time()
     k = start
     cum_bits = resumed_bits if resumed_bits is not None else wire_per_step * start
     stale_hist = None
@@ -440,9 +466,12 @@ def main(argv=None) -> dict:
         # can donate our buffers without the per-chunk protective deep copy.
         # k_start keeps batches and scenario realizations aligned with the
         # *global* step index across chunk dispatches.
+        chunk_start = time.perf_counter()
         state, metrics, info = runner(
             state, make_batch, length, copy_state=False, k_start=k0, aux=aux
         )
+        # the runner returns after reading the chunk's metrics back
+        chunk_s = time.perf_counter() - chunk_start
         aux = info["aux"]
         k += info["steps_dispatched"]
         losses.append(np.asarray(metrics["loss_mean"]))
@@ -457,43 +486,48 @@ def main(argv=None) -> dict:
             row = rows.reshape(-1, rows.shape[-1]).sum(axis=0)
             stale_hist = row if stale_hist is None else stale_hist + row
         if (k // log_every) != (k0 // log_every) or k >= args.steps:
-            lm = np.asarray(metrics["loss_mean"])
-            loss = float(np.mean(lm))
-            extra = ""
-            if lanes:  # spread of the seed replicas at the last step
-                extra += f" loss_std={float(np.std(lm[-1])):.4f}"
-            last = lambda key: float(np.mean(np.asarray(metrics[key])[-1]))
-            if "consensus" in metrics:
-                extra += f" consensus={last('consensus'):.3e}"
-            if "comm_nodes" in metrics:
-                extra += f" comm_nodes={last('comm_nodes'):.0f}"
-            if "alive_nodes" in metrics:
-                extra += f" alive={last('alive_nodes'):.0f}"
-            if "stale_nodes" in metrics:
-                extra += f" stale={last('stale_nodes'):.0f}"
-            if "crashed_nodes" in metrics:
-                extra += f" crashed={last('crashed_nodes'):.0f}"
-            if "dropped_msgs" in metrics:
-                extra += f" dropped={last('dropped_msgs'):.0f}"
-            if "mean_drift" in metrics:
-                extra += f" drift={last('mean_drift'):.3f}"
-            if "surrogate_desync" in metrics:
-                extra += f" desync={last('surrogate_desync'):.3e}"
-            if "sigma_mean" in metrics:
-                extra += f" sigma={last('sigma_mean'):.2f}"
-            print(
-                f"[train] step={k} loss={loss:.4f}{extra}"
-                f" wire_gbits={cum_bits/1e9:.4f}"
-                f" ({(time.time()-t0)/(k-start):.2f}s/step)",
-                flush=True,
-            )
+            with jax.profiler.TraceAnnotation("train.log"):
+                lm = np.asarray(metrics["loss_mean"])
+                loss = float(np.mean(lm))
+                extra = ""
+                if lanes:  # spread of the seed replicas at the last step
+                    extra += f" loss_std={float(np.std(lm[-1])):.4f}"
+                last = lambda key: float(np.mean(np.asarray(metrics[key])[-1]))
+                if "consensus" in metrics:
+                    extra += f" consensus={last('consensus'):.3e}"
+                if "comm_nodes" in metrics:
+                    extra += f" comm_nodes={last('comm_nodes'):.0f}"
+                if "alive_nodes" in metrics:
+                    extra += f" alive={last('alive_nodes'):.0f}"
+                if "stale_nodes" in metrics:
+                    extra += f" stale={last('stale_nodes'):.0f}"
+                if "crashed_nodes" in metrics:
+                    extra += f" crashed={last('crashed_nodes'):.0f}"
+                if "dropped_msgs" in metrics:
+                    extra += f" dropped={last('dropped_msgs'):.0f}"
+                if "mean_drift" in metrics:
+                    extra += f" drift={last('mean_drift'):.3f}"
+                if "surrogate_desync" in metrics:
+                    extra += f" desync={last('surrogate_desync'):.3e}"
+                if "sigma_mean" in metrics:
+                    extra += f" sigma={last('sigma_mean'):.2f}"
+                print(
+                    f"[train] step={k} loss={loss:.4f}{extra}"
+                    f" wire_gbits={cum_bits/1e9:.4f}"
+                    f" ({chunk_s / info['steps_dispatched']:.2f}s/step"
+                    f"{', compiling' if k0 == start else ''})"
+                    + "".join(f" (built chunk program: {n} rounds)" for n in built),
+                    flush=True,
+                )
+                built.clear()
         if args.ckpt_dir and k >= next_ckpt:
-            payload = {"state": state,
-                       "cum_bits": np.asarray(cum_bits, np.float64)}
-            if carries_aux:
-                payload["aux"] = aux
-            save_checkpoint(args.ckpt_dir, k, payload)
-            next_ckpt = (k // args.ckpt_every + 1) * args.ckpt_every
+            with jax.profiler.TraceAnnotation("train.checkpoint"):
+                payload = {"state": state,
+                           "cum_bits": np.asarray(cum_bits, np.float64)}
+                if carries_aux:
+                    payload["aux"] = aux
+                save_checkpoint(args.ckpt_dir, k, payload)
+                next_ckpt = (k // args.ckpt_every + 1) * args.ckpt_every
     if stale_hist is not None:
         total = max(float(stale_hist.sum()), 1.0)
         cells = " ".join(
@@ -501,9 +535,7 @@ def main(argv=None) -> dict:
             for t, c in enumerate(stale_hist)
         )
         print(f"[train] staleness histogram (participant-steps): {cells}")
-    print("[train] done")
-    return {"loss": np.concatenate(losses) if losses else np.zeros((0,)),
-            "n_params": n_params}
+    return losses
 
 
 if __name__ == "__main__":

@@ -93,3 +93,50 @@ def test_ssd_scan_compiles_for_v5e(one_chip):
         sds((b, nc, l, h), jnp.float32), sds((b, nc, l, g, n), jnp.bfloat16),
         sds((b, nc, l, g, n), jnp.bfloat16),
     )
+
+
+# Custom calls the TPU compiler adds for its own buffer bookkeeping; they
+# do no work of the program's and carry no op_name.
+BOOKKEEPING_CALLS = ("AllocateBuffer", "ConcatBitcast")
+
+
+def test_pame_chunk_is_named_by_scope_for_v5e(one_chip):
+    """The bound PaME step's scan chunk at smoke widths (dense mixing,
+    bernoulli masks, the CLI's hyperparameters), compiled for a v5e: every
+    scope of the round appears, and at least 95% of the fusions and
+    custom calls outside fused computations map to one."""
+    import re
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    from bench import scopes
+    from repro.core import engine
+    from repro.launch import train
+
+    args = train.parse_args([
+        "--arch", "stablelm-1.6b", "--variant", "smoke", "--nodes", "4",
+        "--mixing", "dense", "--batch", "2", "--seq", "32", "--chunk", "4"])
+    _, bound, state, make_batch, _, _ = train.build_everything(args)
+    run = engine.make_scan_runner(bound.step, chunk_size=4)
+    run(state, make_batch, 4, copy_state=False)  # on the CPU, for the arguments
+    (chunk, arguments), = run.chunk_programs().values()
+    on_chip = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), arguments)
+    text = chunk.lower(*on_chip).compile().as_text()
+
+    mapping = scopes.scope_map(text)
+    paths = {p for p in mapping.values() if p is not None}
+    assert set(scopes.SCOPES) == {name for p in paths for name in p}
+    computations = scopes.parse(text)
+    fused = {callee for insts in computations.values()
+             for _, _, op, _, calls in insts if op == "fusion" for callee in calls}
+    bookkeeping = set(re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"(?:"
+        + "|".join(BOOKKEEPING_CALLS) + r")\"", text))
+    work = [inst for name, insts in computations.items() if name not in fused
+            for inst, _, op, _, _ in insts
+            if op in ("fusion", "custom-call") and inst not in bookkeeping]
+    mapped = sum(mapping[inst] is not None for inst in work)
+    assert len(work) > 100 and mapped >= 0.95 * len(work), (mapped, len(work))
