@@ -265,6 +265,10 @@ def pame_step(
             "comm_nodes": jnp.sum(comm_mask.astype(jnp.int32)),
             "sigma_mean": jnp.mean(new_state.sigma),
         }
+        if not sparse:
+            # senders some receiver selected: the only ones whose masks the
+            # fused bernoulli kernel draws (a zero row of A is never sent)
+            metrics["senders_drawn"] = jnp.sum(jnp.any(a != 0, axis=1).astype(jnp.int32))
         if realization is not None:
             # realized Eq.-(8) accounting: each selected surviving neighbor
             # transmits one sparse message, in the int8 wire format when

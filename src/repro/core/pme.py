@@ -39,9 +39,13 @@ __all__ = [
 ]
 
 
-# exact-mode leaves at least this large route through the fused Pallas
-# kernel (kernels.pme_average); smaller ones stay on the plain einsum.
+# leaves at least this large route through the fused Pallas kernels
+# (kernels.pme_average) on an accelerator; smaller ones stay on the einsum.
 _KERNEL_MIN_ELEMS = 1 << 17
+
+# jax.monitoring event recorded when a traced exchange draws bernoulli masks
+# inside the fused kernel, with the leaves and coordinates it routes there
+FUSED_MASK_EVENT = "/repro/pme/fused_mask"
 
 
 def sample_coordinate_masks(
@@ -206,6 +210,7 @@ def pme_average_pytree(
     m = leaves[0].shape[0]
     per_leaf = isinstance(p, (tuple, list))
     out = []
+    fused = []  # coordinates of the leaves whose masks the kernel draws
     for idx, leaf in enumerate(leaves):
         own = self_leaves[idx]
         p_i = p[idx] if per_leaf else p
@@ -216,11 +221,52 @@ def pme_average_pytree(
                 n = flat.shape[1]
                 s = max(1, int(round(p_i * n)))
                 masks = sample_coordinate_masks(lkey, m, n, s, mode="exact")
+            elif mode == "bernoulli" and _draws_in_kernel(leaf, lkey, own):
+                masks = None
             else:
                 masks = jax.random.bernoulli(lkey, p_i, leaf.shape)
         with jax.named_scope("pme.average"):
-            out.append(_average_leaf(leaf, masks, a, own, mode))
+            if masks is None:
+                from repro.kernels.pme_average.ops import pme_bernoulli_average
+
+                fused.append(leaf.size)
+                out.append(pme_bernoulli_average(leaf, lkey, a, p_i))
+            else:
+                out.append(_average_leaf(leaf, masks, a, own, mode))
+    if fused:
+        jax.monitoring.record_event(
+            FUSED_MASK_EVENT, leaves=len(fused), coordinates=sum(fused)
+        )
     return jax.tree_util.tree_unflatten(treedef, out)
+
+
+def _accelerator() -> bool:
+    """Whether the kernels run compiled: on the CPU they exist only in the
+    (much slower) interpret mode, so the einsum paths are taken there."""
+    return jax.default_backend() != "cpu"
+
+
+def _draws_in_kernel(leaf, key, own) -> bool:
+    """Whether a bernoulli leaf's masks are drawn inside the fused kernel,
+    bit for bit `jax.random.bernoulli(key, p, leaf.shape)`, and never
+    materialised: on an accelerator, for a leaf of at least
+    `_KERNEL_MIN_ELEMS` and fewer than 2^32 coordinates whose every node
+    fits the kernel's block, with the fallback read from the leaf itself,
+    under the partitionable threefry PRNG (the one whose draw of a
+    coordinate depends on that coordinate's index alone)."""
+    if own is not None or not _accelerator():
+        return False
+    if not _KERNEL_MIN_ELEMS <= leaf.size < 1 << 32:
+        return False
+    if jax.dtypes.issubdtype(key.dtype, jax.dtypes.prng_key):
+        impl = str(jax.random.key_impl(key))
+    else:
+        impl = jax.config.jax_default_prng_impl
+    if impl != "threefry2x32" or not jax.config.jax_threefry_partitionable:
+        return False
+    from repro.kernels.pme_average.ops import bernoulli_fits
+
+    return bernoulli_fits(leaf)
 
 
 def _average_leaf(leaf, masks, a, own, mode: str):
@@ -234,10 +280,7 @@ def _average_leaf(leaf, masks, a, own, mode: str):
 
         if own is None and (
             default_impl() == "pallas"
-            or (
-                flat.size >= _KERNEL_MIN_ELEMS
-                and jax.default_backend() != "cpu"
-            )
+            or (flat.size >= _KERNEL_MIN_ELEMS and _accelerator())
         ):
             # hot path: fused Pallas kernel (1 HBM read + 1 write of the
             # [m, n] operand).  By size/backend gate, tiny leaves stay on

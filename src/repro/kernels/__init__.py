@@ -1,7 +1,9 @@
 """Pallas TPU kernels for the compute hot spots.
 
   pme_average     — the paper's PME count-weighted masked average, fused
-                    (mask-mul + two MXU matmuls + divide + self-fill);
+                    (mask-mul + two MXU matmuls + divide + self-fill), and
+                    its bernoulli form, which draws the selected senders'
+                    masks in the kernel;
   flash_attention — blockwise causal GQA attention (opt. sliding window);
   ssd_scan        — Mamba2 SSD intra-chunk contraction.
 

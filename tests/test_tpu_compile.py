@@ -56,6 +56,19 @@ def test_pme_average_compiles_for_v5e(one_chip):
     _compile(pme_average_pallas, w, w, a)
 
 
+def test_pme_bernoulli_average_compiles_for_v5e(one_chip):
+    """The embedding leaf of the stablelm-1.6b cell (m = 4, bf16 [100352,
+    2048]) with its bernoulli masks drawn in the kernel: no mask and no f32
+    sums reach HBM (the einsum path's temporaries are 7.40 GB)."""
+    from repro.kernels.pme_average.kernel import pme_bernoulli_average_pallas
+
+    w = jax.ShapeDtypeStruct((1, 4, 100352, 2048), jnp.bfloat16, sharding=one_chip)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    a = jax.ShapeDtypeStruct((4, 4), jnp.float32, sharding=one_chip)
+    compiled = _compile(lambda w, k, a: pme_bernoulli_average_pallas(w, k, a, 0.2), w, key, a)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 def test_gossip_compiles_for_v5e(one_chip):
     """m = 32 nodes at degree 8, PME's two terms (payload and counts) on
     one shared weight table, over one stablelm-1.6b MLP matrix in f32."""
@@ -100,19 +113,13 @@ def test_ssd_scan_compiles_for_v5e(one_chip):
 BOOKKEEPING_CALLS = ("AllocateBuffer", "ConcatBitcast")
 
 
-def test_pame_chunk_is_named_by_scope_for_v5e(one_chip):
-    """The bound PaME step's scan chunk at smoke widths (dense mixing,
-    bernoulli masks, the CLI's hyperparameters), compiled for a v5e: every
-    scope of the round appears, and at least 95% of the fusions and
-    custom calls outside fused computations map to one."""
-    import re
-    import sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if repo not in sys.path:
-        sys.path.insert(0, repo)
-    from bench import scopes
-    from repro.core import engine
+def _smoke_chunk_for_v5e(one_chip, fused: bool = False) -> str:
+    """The optimized HLO of the bound PaME step's scan chunk at smoke widths
+    (dense mixing, bernoulli masks, the CLI's hyperparameters) compiled for
+    a v5e; ``fused`` takes the gate of the fused bernoulli kernel as on a
+    TPU.  The chunk runs on the CPU first, for its arguments."""
+    from repro.core import engine, pme
+    from repro.kernels.pme_average import ops
     from repro.launch import train
 
     args = train.parse_args([
@@ -124,19 +131,80 @@ def test_pame_chunk_is_named_by_scope_for_v5e(one_chip):
     (chunk, arguments), = run.chunk_programs().values()
     on_chip = jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), arguments)
-    text = chunk.lower(*on_chip).compile().as_text()
+    with pytest.MonkeyPatch.context() as mp:
+        if fused:
+            mp.setattr(pme, "_accelerator", lambda: True)
+            mp.setattr(ops, "_on_cpu", lambda: False)
+            jax.clear_caches()  # trace the chunk again, through the gate
+        return chunk.lower(*on_chip).compile().as_text()
 
-    mapping = scopes.scope_map(text)
-    paths = {p for p in mapping.values() if p is not None}
-    assert set(scopes.SCOPES) == {name for p in paths for name in p}
+
+def _work(text: str) -> list:
+    """The fusions and custom calls of a module outside fused computations,
+    but the compiler's own bookkeeping calls."""
+    import re
+
+    from bench import scopes
+
     computations = scopes.parse(text)
     fused = {callee for insts in computations.values()
              for _, _, op, _, calls in insts if op == "fusion" for callee in calls}
     bookkeeping = set(re.findall(
         r"%([\w.\-]+) = [^\n]*custom_call_target=\"(?:"
         + "|".join(BOOKKEEPING_CALLS) + r")\"", text))
-    work = [inst for name, insts in computations.items() if name not in fused
+    return [inst for name, insts in computations.items() if name not in fused
             for inst, _, op, _, _ in insts
             if op in ("fusion", "custom-call") and inst not in bookkeeping]
+
+
+@pytest.fixture
+def bench_importable():
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+
+
+def test_pame_chunk_is_named_by_scope_for_v5e(one_chip, bench_importable):
+    """Every scope of the round appears in the compiled chunk, and at least
+    95% of the fusions and custom calls outside fused computations map to
+    one."""
+    from bench import scopes
+
+    text = _smoke_chunk_for_v5e(one_chip)
+    mapping = scopes.scope_map(text)
+    paths = {p for p in mapping.values() if p is not None}
+    assert set(scopes.SCOPES) == {name for p in paths for name in p}
+    work = _work(text)
     mapped = sum(mapping[inst] is not None for inst in work)
     assert len(work) > 100 and mapped >= 0.95 * len(work), (mapped, len(work))
+
+
+def test_pame_chunk_with_fused_masks_is_named_by_scope_for_v5e(one_chip, bench_importable):
+    """With the fused bernoulli kernel (every smoke leaf of 2^17 or more
+    coordinates), each kernel call is a TPU custom call under
+    ``pame.exchange``/``pme.average``, the norm scales' draws still under
+    ``pme.mask``, and the round stays as fully named."""
+    import re
+
+    from bench import scopes
+    from repro.core import pme
+
+    events = []
+    listener = lambda event, **kw: events.append(kw) if event == pme.FUSED_MASK_EVENT else None
+    jax.monitoring.register_event_listener(listener)
+    try:
+        text = _smoke_chunk_for_v5e(one_chip, fused=True)
+    finally:
+        jax.monitoring.unregister_event_listener(listener)
+    assert {e["leaves"] for e in events} == {8}
+    mapping = scopes.scope_map(text)
+    kernels = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
+    assert len(kernels) == 8
+    assert {mapping[k] for k in kernels} == {("pame.exchange", "pme.average")}
+    paths = {p for p in mapping.values() if p is not None}
+    assert set(scopes.SCOPES) == {name for p in paths for name in p}
+    work = _work(text)
+    mapped = sum(mapping[inst] is not None for inst in work)
+    assert mapped >= 0.95 * len(work), (mapped, len(work))
