@@ -1,4 +1,5 @@
-"""Architecture registry: the 10 assigned archs (+ the paper's own models).
+"""Architecture registry: the 10 assigned archs (+ the paper's own models)
+and the chip shares of them that a deployment divides over chips.
 
 Every module exposes FULL (exact assigned config) and SMOKE (reduced:
 <=2 layers, d_model <= 512, <=4 experts) ModelConfigs.  `get_config(name,
@@ -22,6 +23,7 @@ ARCH_IDS: List[str] = [
     "musicgen_large",
     "deepseek_v2_lite_16b",
     "qwen3_14b",
+    "deepseek_v2_lite_16b_ep8",
 ]
 
 # CLI aliases (the assignment's spelling) -> module names
@@ -36,6 +38,7 @@ ALIASES = {
     "musicgen-large": "musicgen_large",
     "deepseek-v2-lite-16b": "deepseek_v2_lite_16b",
     "qwen3-14b": "qwen3_14b",
+    "deepseek-v2-lite-16b-ep8": "deepseek_v2_lite_16b_ep8",
 }
 
 

@@ -45,6 +45,5 @@ SMOKE = FULL.replace(
     n_shared_experts=1,
     moe_top_k=2,
     d_ff_expert=64,
-    capacity_factor=4.0,
     dtype="float32",
 )

@@ -1,8 +1,10 @@
 """deepseek-v2-lite-16b [moe] — MLA kv_lora=512, 2 shared + 64 routed
-top-6 [arXiv:2405.04434].
+top-6 [arXiv:2405.04434; hf:deepseek-ai/DeepSeek-V2-Lite config.json].
 
-27L d_model=2048 16H, per-expert d_ff=1408, vocab=102400, first layer
-dense (d_ff=10944); lite variant has no q LoRA.
+27L d_model=2048 16H, per-expert d_ff=1408, vocab=102400 with an untied
+head, first layer dense (d_ff=10944); no q LoRA; YaRN rope (factor 40
+over 4096 positions, mscale 0.707); softmax gate, greedy top-6, not
+renormalised; sequence-wise balance loss (alpha 0.001).
 """
 from repro.models.config import ModelConfig
 
@@ -20,12 +22,22 @@ FULL = ModelConfig(
     q_lora=0,
     rope_head_dim=64,
     v_head_dim=128,
+    rope_theta=10000.0,
+    yarn_factor=40.0,
+    yarn_original_max_position=4096,
+    yarn_beta_fast=32.0,
+    yarn_beta_slow=1.0,
+    yarn_mscale=0.707,
+    yarn_mscale_all_dim=0.707,
     d_ff=10944,
     n_experts=64,
     n_shared_experts=2,
     moe_top_k=6,
+    norm_topk_prob=False,
     d_ff_expert=1408,
     first_dense_layers=1,
+    router_aux_coef=0.001,
+    tie_embeddings=False,
     dtype="bfloat16",
 )
 
@@ -46,6 +58,5 @@ SMOKE = FULL.replace(
     n_shared_experts=1,
     moe_top_k=2,
     d_ff_expert=64,
-    capacity_factor=4.0,
     dtype="float32",
 )

@@ -116,6 +116,8 @@ class AlgoContext:
     hps: object
     mixer: Mixer
     extras: dict
+    # grad_fn returns ((loss, {name: count}), grads) (``Algorithm.counts``)
+    grad_counts: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -169,6 +171,13 @@ class Algorithm:
     # from ``ctx.extras["fault"]`` (see ``repro.core.faults``).
     rep_init: Optional[Callable] = None
     rep_step: Optional[Callable] = None
+    # the step takes a counted grad_fn (``bind(grad_counts=True)``) and sums
+    # its counts over the nodes into the round's metrics
+    counts: bool = False
+
+    def _check_counts(self, grad_counts: bool):
+        if grad_counts and not self.counts:
+            raise ValueError(f"{self.name} does not take a counted grad_fn")
 
     def bind(
         self,
@@ -181,6 +190,7 @@ class Algorithm:
         scenario: Optional[AnyScenario] = None,
         faults: Optional[flt_mod.FaultModel] = None,
         pacing: Optional[ServePacing] = None,
+        grad_counts: bool = False,
     ) -> "BoundAlgorithm":
         """Close the spec over (grad_fn, topology, hps, mixing, scenario).
 
@@ -217,6 +227,7 @@ class Algorithm:
         zero-rate pacing binds the plain unpaced program, bit-identical
         to ``pacing=None``.
         """
+        self._check_counts(grad_counts)
         hps = self.hp_cls() if hps is None else hps
         if not isinstance(hps, self.hp_cls):
             raise TypeError(
@@ -227,7 +238,7 @@ class Algorithm:
             hps = extras.pop("hps")
         mixer = make_mixer(topo, "matrix" if mixing == "matrix" else mixing)
         ctx = AlgoContext(grad_fn=grad_fn, topo=topo, hps=hps, mixer=mixer,
-                          extras=extras)
+                          extras=extras, grad_counts=grad_counts)
         if faults is not None and faults.is_static:
             faults = None  # zero-rate model == the fault-free program
         if pacing is not None and pacing.is_static:
@@ -267,6 +278,7 @@ class Algorithm:
         scenario: Optional[AnyScenario] = None,
         faults: Optional[flt_mod.FaultModel] = None,
         pacing: Optional[ServePacing] = None,
+        grad_counts: bool = False,
     ) -> "BatchedAlgorithm":
         """Close the spec over S seeds × C configs as ONE lane-batched step.
 
@@ -296,6 +308,7 @@ class Algorithm:
         arrival-process key the same way — independent request traces
         per seed, shared across configs.
         """
+        self._check_counts(grad_counts)
         hps_list = [self.hp_cls() if h is None else h
                     for h in (hps_list or [None])]
         for h in hps_list:
@@ -361,7 +374,7 @@ class Algorithm:
 
         mixer = make_mixer(topo, "matrix" if mixing == "matrix" else mixing)
         ctx0 = AlgoContext(grad_fn=grad_fn, topo=topo, hps=hps0, mixer=mixer,
-                           extras=shared_extras)
+                           extras=shared_extras, grad_counts=grad_counts)
         if faults is not None and faults.is_static:
             faults = None  # zero-rate model == the fault-free program
         if pacing is not None and pacing.is_static:
@@ -1327,10 +1340,11 @@ register(Algorithm(
         state, batch, ctx.grad_fn, ctx.extras["topo_arrays"], ctx.hps,
         realization=ctx.extras.get("realization"),
         self_params=ctx.extras.get("fresh_params"),
-        delivered=ctx.extras.get("delivered")),
+        delivered=ctx.extras.get("delivered"), counted=ctx.grad_counts),
     wire_bits=_pame_wire_bits,
     wire_bits_sizes=_pame_wire_bits_sizes,
     setup=_pame_setup,
+    counts=True,
     # dense-exchange PaME consumes message-only delay natively: senders
     # transmit the ring-delayed stack while the lambda=0 / uncovered-
     # coordinate fallback reads the fresh self-view — no innovation

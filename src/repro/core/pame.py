@@ -33,7 +33,8 @@ __all__ = [
     "pame_init", "pame_step", "make_pame_runner", "run_pame",
 ]
 
-# grad_fn(params_i, batch_i, key) -> (loss_i, grads_i)
+# grad_fn(params_i, batch_i, key) -> (loss_i, grads_i); a counted one
+# (pame_step's ``counted``) -> ((loss_i, {name: count}), grads_i)
 GradFn = Callable[[object, object, jax.Array], Tuple[jax.Array, object]]
 
 
@@ -165,6 +166,8 @@ def pame_step(
     # charged) regardless; only delivered ones enter the average.  PME's
     # count normalization keeps the realized averaging row-stochastic under
     # arbitrary asymmetric loss, with the lambda=0 fill as the limit case.
+    counted: bool = False,  # grad_fn is counted: its counts, summed over
+    # the nodes, join the round's metrics (a MoE model's ``expert_rows``)
 ) -> Tuple[PaMEState, dict]:
     m = topo.nbrs.shape[0]
     sparse = cfg.exchange == "dense" and cfg.mixing == "sparse"
@@ -239,6 +242,9 @@ def pame_step(
     with jax.named_scope("pame.local_step"):
         node_keys = jax.random.split(k_data, m)
         losses, grads = jax.vmap(grad_fn)(v_bar, batch, node_keys)
+        counts = {}
+        if counted:
+            losses, counts = losses
 
     with jax.named_scope("pame.update"):
         stepsize = 1.0 / (state.sigma * topo.t.astype(jnp.float32))
@@ -264,6 +270,7 @@ def pame_step(
             "consensus": consensus,
             "comm_nodes": jnp.sum(comm_mask.astype(jnp.int32)),
             "sigma_mean": jnp.mean(new_state.sigma),
+            **{name: jnp.sum(c) for name, c in counts.items()},
         }
         if not sparse:
             # senders some receiver selected: the only ones whose masks the

@@ -56,7 +56,7 @@ from repro.core.scenarios import get_scenario, list_scenarios
 from repro.core.temporal import TemporalScenario
 from repro.core.topology import build_topology
 from repro.data.synthetic import SyntheticTokens
-from repro.models.model import init_params, train_loss
+from repro.models.model import init_params, train_loss, train_loss_counted
 
 
 def _hps_from_args(name: str, args):
@@ -209,12 +209,19 @@ def build_everything(args):
             )
         return batch
 
-    def grad_fn(p, b, k):
-        del k
-        return jax.value_and_grad(lambda pp: train_loss(pp, cfg, b))(p)
-
     alg = get_algorithm(args.algo)
     hps = _hps_from_args(args.algo, args)
+    # a MoE model's counters (expert rows) join the round metrics of an
+    # algorithm that takes them
+    counted = alg.counts and cfg.arch_type == "moe"
+
+    def grad_fn(p, b, k):
+        del k
+        if counted:
+            return jax.value_and_grad(
+                lambda pp: train_loss_counted(pp, cfg, b), has_aux=True)(p)
+        return jax.value_and_grad(lambda pp: train_loss(pp, cfg, b))(p)
+
     scen = _scenario_from_args(args)
     faults = _faults_from_args(args)
     params0 = init_params(jax.random.PRNGKey(args.seed), cfg)
@@ -227,14 +234,14 @@ def build_everything(args):
             grad_fn, topo, [hps],
             seeds=[args.seed + 1 + i for i in range(args.seeds)],
             mixing=args.mixing, seed=args.seed, scenario=scen,
-            faults=faults,
+            faults=faults, grad_counts=counted,
         )
         state = bound.init(params0, m, batch0)
     else:
         bound = alg.bind(
             grad_fn, topo, hps,
             mixing=args.mixing, seed=args.seed, scenario=scen,
-            faults=faults,
+            faults=faults, grad_counts=counted,
         )
         stacked = jax.tree_util.tree_map(
             lambda x: jnp.broadcast_to(x[None], (m,) + x.shape), params0

@@ -8,6 +8,7 @@ from repro.models.config import ModelConfig  # noqa: F401
 from repro.models.model import (  # noqa: F401
     init_params,
     train_loss,
+    train_loss_counted,
     prefill,
     decode_step,
     init_cache,

@@ -20,7 +20,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.config import ModelConfig
-from repro.models.layers import apply_rope, dense_init, linear, rms_norm, rope_freqs
+from repro.models.layers import (
+    apply_rope, dense_init, linear, rms_norm, rope_freqs, yarn_mscale,
+)
 
 __all__ = [
     "KVCache",
@@ -245,7 +247,7 @@ def _mla_q(params: dict, cfg: ModelConfig, x: jax.Array, positions: jax.Array):
         cq = x
     q = linear(cq, params["w_uq"]).reshape(b, s, h, nope + rope_hd)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    cos, sin = rope_freqs(positions, rope_hd, cfg.rope_theta)
+    cos, sin = rope_freqs(positions, rope_hd, cfg.rope_theta, cfg)
     q_rope = apply_rope(q_rope, cos[None], sin[None])
     return q_nope, q_rope
 
@@ -254,9 +256,18 @@ def _mla_ckv(params: dict, cfg: ModelConfig, x: jax.Array, positions: jax.Array)
     ckv_full = linear(x, params["w_dkv"])
     c_kv = rms_norm(ckv_full[..., : cfg.kv_lora], params["kv_norm"])
     k_rope = ckv_full[..., cfg.kv_lora :]
-    cos, sin = rope_freqs(positions, cfg.rope_head_dim, cfg.rope_theta)
+    cos, sin = rope_freqs(positions, cfg.rope_head_dim, cfg.rope_theta, cfg)
     k_rope = apply_rope(k_rope, cos[None], sin[None])
     return c_kv, k_rope
+
+
+def _mla_scale(cfg: ModelConfig) -> float:
+    """Softmax scale: (nope + rope)^-1/2, times YaRN's temperature term
+    squared where the configuration scales the rope (DeepSeek-V2)."""
+    scale = (cfg.head_dim + cfg.rope_head_dim) ** -0.5
+    if cfg.yarn_factor and cfg.yarn_mscale_all_dim:
+        scale *= yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return scale
 
 
 def mla_apply(
@@ -267,14 +278,20 @@ def mla_apply(
     return_cache: bool = False,
     cache_capacity: Optional[int] = None,
 ) -> Tuple[jax.Array, Optional[MLACache]]:
-    """Full-sequence MLA with per-head expansion (train / prefill)."""
+    """Full-sequence MLA with per-head expansion (train / prefill), named
+    ``mla.attend`` in a device trace."""
+    with jax.named_scope("mla.attend"):
+        return _mla_full(params, cfg, x, positions, return_cache, cache_capacity)
+
+
+def _mla_full(params, cfg, x, positions, return_cache, cache_capacity):
     b, s, _ = x.shape
     h, nope, v_hd = cfg.n_heads, cfg.head_dim, cfg.v_head_dim
     q_nope, q_rope = _mla_q(params, cfg, x, positions)
     c_kv, k_rope = _mla_ckv(params, cfg, x, positions)
     k_nope = linear(c_kv, params["w_uk"]).reshape(b, s, h, nope)
     v = linear(c_kv, params["w_uv"]).reshape(b, s, h, v_hd)
-    scale = (nope + cfg.rope_head_dim) ** -0.5
+    scale = _mla_scale(cfg)
 
     def _attend(qn, qr, rows):  # qn [B,C,H,nope], rows [C]
         sc = (
@@ -344,7 +361,7 @@ def mla_decode(
     # absorb W_uk into the query:  q_eff[b,h,c] = q_nope . W_uk[:, h, :]
     w_uk = params["w_uk"].reshape(cfg.kv_lora, h, nope)
     q_eff = jnp.einsum("bshn,chn->bshc", q_nope, w_uk)[:, 0]  # [B,H,kv_lora]
-    scale = (nope + cfg.rope_head_dim) ** -0.5
+    scale = _mla_scale(cfg)
     scores = (
         jnp.einsum("bhc,btc->bht", q_eff, c_kv)
         + jnp.einsum("bshr,btr->bht", q_rope, k_rope)

@@ -34,6 +34,16 @@ class ModelConfig:
     head_dim: int = 0
     qk_norm: bool = False
     rope_theta: float = 10_000.0
+    # YaRN rope scaling of MLA (Peng et al. 2023, as DeepSeek-V2 applies
+    # it): frequencies interpolated by yarn_factor outside the band that
+    # beta_fast/beta_slow rotations over the original context mark out, the
+    # softmax scaled by (0.1 mscale_all_dim ln factor + 1)^2; 0 = plain rope
+    yarn_factor: float = 0.0
+    yarn_original_max_position: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     window: Optional[int] = None  # sliding-window size; None = full causal
 
     # --- dense mlp ---
@@ -47,14 +57,15 @@ class ModelConfig:
     v_head_dim: int = 0
 
     # --- MoE ---
-    n_experts: int = 0
+    n_experts: int = 0       # the router's width: every expert of a layer
+    experts_held: int = 0    # experts 0..experts_held-1 live here (expert
+                             # parallelism: this chip's share); 0 = all
     n_shared_experts: int = 0
     moe_top_k: int = 0
+    norm_topk_prob: bool = True  # renormalise the top-k gate to sum to 1
     d_ff_expert: int = 0
     first_dense_layers: int = 0
-    capacity_factor: float = 1.25
-    router_aux_coef: float = 0.01
-    router_z_coef: float = 1e-3
+    router_aux_coef: float = 0.01  # sequence-wise balance loss coefficient
 
     # --- SSM (Mamba2 SSD) ---
     ssm_state: int = 0
@@ -105,6 +116,10 @@ class ModelConfig:
         return self.arch_type == "ssm"
 
     @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.n_experts
+
+    @property
     def qk_nope_dim(self) -> int:
         return self.head_dim  # MLA: per-head non-rope dim
 
@@ -128,6 +143,7 @@ class ModelConfig:
             attn += d * (self.kv_lora + self.rope_head_dim)
             attn += self.kv_lora * self.n_heads * (self.head_dim + self.v_head_dim)
             attn += self.n_heads * self.v_head_dim * d
+            attn += self.kv_lora + self.q_lora  # the latents' norm scales
         elif self.n_heads:
             attn += d * self.n_heads * self.head_dim
             attn += 2 * d * self.n_kv_heads * self.head_dim
@@ -137,7 +153,7 @@ class ModelConfig:
         if self.n_experts:
             moe = (
                 d * self.n_experts
-                + self.n_experts * 3 * d * self.d_ff_expert
+                + self.held_experts * 3 * d * self.d_ff_expert
                 + self.n_shared_experts * 3 * d * self.d_ff_expert
             )
         mamba = 0
@@ -153,8 +169,9 @@ class ModelConfig:
         if self.arch_type == "dense" or self.arch_type in ("vlm", "audio"):
             total += self.n_layers * (attn + mlp_dense + 4 * d)
         elif self.arch_type == "moe":
-            total += self.first_dense_layers * (attn + mlp_dense + 4 * d)
-            total += (self.n_layers - self.first_dense_layers) * (attn + moe + 4 * d)
+            # two norm scales a layer, one before each block
+            total += self.first_dense_layers * (attn + mlp_dense + 2 * d)
+            total += (self.n_layers - self.first_dense_layers) * (attn + moe + 2 * d)
         elif self.arch_type == "ssm":
             total += self.n_layers * (mamba + 2 * d)
         elif self.arch_type == "hybrid":
@@ -163,13 +180,13 @@ class ModelConfig:
         return total
 
     def active_param_count(self) -> int:
-        """Active params per token (MoE: shared + top-k routed)."""
+        """Active params per token (MoE: shared + top-k routed; of a share
+        holding H of E experts, top-k * H / E routed experts a token on
+        average)."""
         if not self.n_experts:
             return self.param_count()
-        d = self.d_model
-        full_moe_layer = (
-            self.n_experts * 3 * d * self.d_ff_expert
-        )
-        active_moe_layer = self.moe_top_k * 3 * d * self.d_ff_expert
+        expert = 3 * self.d_model * self.d_ff_expert
+        held = self.held_experts
+        idle = held - self.moe_top_k * held / self.n_experts
         n_moe_layers = self.n_layers - self.first_dense_layers
-        return self.param_count() - n_moe_layers * (full_moe_layer - active_moe_layer)
+        return self.param_count() - round(n_moe_layers * idle * expert)

@@ -1,6 +1,7 @@
 """Shared primitives: norms, rope, initializers, projections."""
 from __future__ import annotations
 
+import math
 from typing import Tuple
 
 import jax
@@ -11,6 +12,8 @@ __all__ = [
     "dense_init",
     "embed_init",
     "rope_freqs",
+    "yarn_inv_freq",
+    "yarn_mscale",
     "apply_rope",
     "linear",
 ]
@@ -38,11 +41,46 @@ def linear(x: jax.Array, w: jax.Array) -> jax.Array:
     return jnp.einsum("...d,df->...f", x, w)
 
 
-def rope_freqs(positions: jax.Array, dim: int, theta: float) -> Tuple[jax.Array, jax.Array]:
-    """positions [...,] int -> (cos, sin) of shape [..., dim/2]."""
-    inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term, 0.1 * mscale * ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, cfg) -> jax.Array:
+    """YaRN inverse frequencies [dim/2] of a config's ``yarn_*`` fields: the
+    plain ones below the band, those divided by ``yarn_factor`` above it,
+    and a linear ramp between.  The band's ends are the dimensions that turn
+    ``yarn_beta_fast`` and ``yarn_beta_slow`` times over
+    ``yarn_original_max_position`` positions."""
+    plain = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+
+    def turning_dim(rotations: float) -> float:
+        return (dim * math.log(cfg.yarn_original_max_position / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turning_dim(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(turning_dim(cfg.yarn_beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low) / (high - low), 0, 1)
+    return plain / cfg.yarn_factor * ramp + plain * (1 - ramp)
+
+
+def rope_freqs(
+    positions: jax.Array, dim: int, theta: float, cfg=None
+) -> Tuple[jax.Array, jax.Array]:
+    """positions [...,] int -> (cos, sin) of shape [..., dim/2]; where
+    ``cfg`` (a ``ModelConfig``) sets ``yarn_factor``, the YaRN frequencies
+    and magnitude of its ``yarn_*`` fields."""
+    if cfg is None or not cfg.yarn_factor:
+        inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+        mag = 1.0
+    else:
+        inv = yarn_inv_freq(dim, theta, cfg)
+        mag = yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale) / yarn_mscale(
+            cfg.yarn_factor, cfg.yarn_mscale_all_dim)
     ang = positions.astype(jnp.float32)[..., None] * inv  # [..., dim/2]
-    return jnp.cos(ang), jnp.sin(ang)
+    return jnp.cos(ang) * mag, jnp.sin(ang) * mag
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:

@@ -13,6 +13,9 @@ the scan axis, so HLO size is independent of depth:
 
 Paths:
   train_loss  — full sequence, next-token CE (+ MoE aux), optional remat;
+  train_loss_counted — the same loss and the pass's counters
+                (``expert_rows``: the (token, choice) pairs the held
+                experts computed);
   prefill     — full sequence, returns logits of last position + caches;
   decode_step — one token against ring-buffer caches (serve_step).
 """
@@ -36,10 +39,16 @@ __all__ = [
     "layer_groups",
     "init_params",
     "train_loss",
+    "train_loss_counted",
     "prefill",
     "decode_step",
     "init_cache",
 ]
+
+
+def _no_aux():
+    """A block's aux: (MoE balance loss, expert rows computed)."""
+    return jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -185,8 +194,9 @@ def _apply_block_full(
     want_cache: bool,
     capacity: int,
 ):
-    """Full-sequence (train/prefill). Returns (x, cache_or_None, aux)."""
-    aux = jnp.zeros((), jnp.float32)
+    """Full-sequence (train/prefill). Returns (x, cache_or_None, aux), aux
+    being (balance loss, expert rows)."""
+    aux = _no_aux()
     if kind == "attn":
         h, cache = attn.gqa_apply(
             bparams["attn"], cfg, rms_norm(x, bparams["ln"]), positions,
@@ -202,8 +212,8 @@ def _apply_block_full(
     if kind == "mlp":
         return x + mlp_apply(bparams["mlp"], rms_norm(x, bparams["ln"])), None, aux
     if kind == "moe":
-        h, aux = moe_mod.moe_apply(bparams["moe"], cfg, rms_norm(x, bparams["ln"]))
-        return x + h, None, aux
+        h, loss, rows = moe_mod.moe_apply(bparams["moe"], cfg, rms_norm(x, bparams["ln"]))
+        return x + h, None, (loss, rows)
     if kind == "mamba":
         h, cache = ssm_mod.mamba_apply(
             bparams["mamba"], cfg, rms_norm(x, bparams["ln"]), return_cache=want_cache
@@ -239,7 +249,7 @@ def _apply_block_decode(
     if kind == "mlp":
         return x + mlp_apply(bparams["mlp"], rms_norm(x, bparams["ln"])), None
     if kind == "moe":
-        h, _ = moe_mod.moe_apply(bparams["moe"], cfg, rms_norm(x, bparams["ln"]))
+        h, _, _ = moe_mod.moe_apply(bparams["moe"], cfg, rms_norm(x, bparams["ln"]))
         return x + h, None
     if kind == "mamba":
         h, c = ssm_mod.mamba_decode(bparams["mamba"], cfg, rms_norm(x, bparams["ln"]), cache)
@@ -267,7 +277,7 @@ def _run_trunk_full(
     shared = params.get("shared_block")
     groups = layer_groups(cfg)
     caches_out = []
-    aux_total = jnp.zeros((), jnp.float32)
+    aux_total = _no_aux()
     for grp, gparams in zip(groups, params["groups"]):
 
         def body(carry, layer_params):
@@ -278,9 +288,10 @@ def _run_trunk_full(
                 h, cache, aux = _apply_block_full(
                     kind, bp, shared, cfg, h, positions, want_cache, capacity
                 )
+                aux_acc = (aux_acc[0] + aux[0], aux_acc[1] + aux[1])
                 if cache is not None:
                     cache_entries[f"{i}_{kind}"] = cache
-            return (h, aux_acc + aux), cache_entries
+            return (h, aux_acc), cache_entries
 
         if cfg.remat:
             if cfg.remat_policy == "dots":
@@ -375,13 +386,14 @@ def _logits(params: dict, cfg: ModelConfig, x: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 # public paths
 # ---------------------------------------------------------------------------
-def train_loss(params: dict, cfg: ModelConfig, batch: dict) -> jax.Array:
-    """Next-token cross-entropy (+ MoE aux).  batch: tokens [B,S]
-    (+ patch_embeds for vlm); loss over text positions only."""
+def train_loss_counted(params: dict, cfg: ModelConfig, batch: dict):
+    """(loss, {"expert_rows": int32}): ``train_loss`` and the (token,
+    choice) pairs the held experts of every MoE layer computed (0 in a
+    model without MoE layers)."""
     x = _embed_inputs(params, cfg, batch)
     s_total = x.shape[1]
     positions = jnp.arange(s_total)
-    x, _, aux = _run_trunk_full(params, cfg, x, positions, False, s_total)
+    x, _, (aux, rows) = _run_trunk_full(params, cfg, x, positions, False, s_total)
     logits = _logits(params, cfg, x)
     tok = batch["tokens"]
     if cfg.arch_type == "vlm":
@@ -391,7 +403,13 @@ def train_loss(params: dict, cfg: ModelConfig, batch: dict) -> jax.Array:
     logz = jax.nn.logsumexp(pred, axis=-1)
     gold = jnp.take_along_axis(pred, tgt[..., None], axis=-1)[..., 0]
     ce = jnp.mean(logz - gold)
-    return ce + aux
+    return ce + aux, {"expert_rows": rows}
+
+
+def train_loss(params: dict, cfg: ModelConfig, batch: dict) -> jax.Array:
+    """Next-token cross-entropy (+ MoE aux).  batch: tokens [B,S]
+    (+ patch_embeds for vlm); loss over text positions only."""
+    return train_loss_counted(params, cfg, batch)[0]
 
 
 def prefill(params: dict, cfg: ModelConfig, batch: dict, capacity: int):

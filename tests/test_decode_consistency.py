@@ -23,7 +23,6 @@ CONFIGS = {
         n_heads=4, n_kv_heads=4, head_dim=16, use_mla=True, kv_lora=32,
         q_lora=24, rope_head_dim=8, v_head_dim=16, d_ff=128, n_experts=4,
         n_shared_experts=1, moe_top_k=2, d_ff_expert=32, first_dense_layers=1,
-        capacity_factor=4.0,
     ),
     "ssm": ModelConfig(
         "ssm", "ssm", n_layers=2, d_model=64, vocab=64,

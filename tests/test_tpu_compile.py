@@ -113,17 +113,21 @@ def test_ssd_scan_compiles_for_v5e(one_chip):
 BOOKKEEPING_CALLS = ("AllocateBuffer", "ConcatBitcast")
 
 
-def _smoke_chunk_for_v5e(one_chip, fused: bool = False) -> str:
+def _smoke_chunk_for_v5e(one_chip, fused: bool = False, arch: str = "stablelm-1.6b",
+                         nodes: int = 4, min_fused: int = None) -> str:
     """The optimized HLO of the bound PaME step's scan chunk at smoke widths
     (dense mixing, bernoulli masks, the CLI's hyperparameters) compiled for
     a v5e; ``fused`` takes the gate of the fused bernoulli kernel as on a
-    TPU.  The chunk runs on the CPU first, for its arguments."""
+    TPU (for leaves of ``min_fused`` coordinates or more where given), and
+    the MoE kernels compiled rather than interpreted.  The chunk runs on
+    the CPU first, for its arguments."""
     from repro.core import engine, pme
     from repro.kernels.pme_average import ops
     from repro.launch import train
+    from repro.models import moe
 
     args = train.parse_args([
-        "--arch", "stablelm-1.6b", "--variant", "smoke", "--nodes", "4",
+        "--arch", arch, "--variant", "smoke", "--nodes", str(nodes),
         "--mixing", "dense", "--batch", "2", "--seq", "32", "--chunk", "4"])
     _, bound, state, make_batch, _, _ = train.build_everything(args)
     run = engine.make_scan_runner(bound.step, chunk_size=4)
@@ -135,6 +139,9 @@ def _smoke_chunk_for_v5e(one_chip, fused: bool = False) -> str:
         if fused:
             mp.setattr(pme, "_accelerator", lambda: True)
             mp.setattr(ops, "_on_cpu", lambda: False)
+            mp.setattr(moe, "_interpret", lambda: False)
+            if min_fused is not None:
+                mp.setattr(pme, "_KERNEL_MIN_ELEMS", min_fused)
             jax.clear_caches()  # trace the chunk again, through the gate
         return chunk.lower(*on_chip).compile().as_text()
 
@@ -208,3 +215,56 @@ def test_pame_chunk_with_fused_masks_is_named_by_scope_for_v5e(one_chip, bench_i
     work = _work(text)
     mapped = sum(mapping[inst] is not None for inst in work)
     assert mapped >= 0.95 * len(work), (mapped, len(work))
+
+
+MOE_SCOPES = ("moe.route", "moe.experts", "moe.shared", "moe.combine", "mla.attend")
+
+
+def test_moe_share_chunk_is_named_by_scope_for_v5e(one_chip, bench_importable):
+    """The DeepSeek-V2-Lite ep8 share (MLA, a dense layer, then routed
+    experts of which the share holds some, and shared ones) over 3 nodes:
+    its grouped matmuls compile to Pallas kernels, every round scope
+    and the model's ``moe.*``/``mla.attend`` scopes appear, at least 95% of
+    the work maps to a round scope, and the expert leaves [m, L, E, d, f]
+    go through the fused bernoulli kernel (here every leaf of 2^14
+    coordinates or more, as every expert leaf at published widths)."""
+    import re
+
+    from bench import scopes
+    from repro.core import pme
+    from repro.models import moe
+
+    events = []
+
+    def listener(event, **kw):
+        events.append((event, kw))
+
+    jax.monitoring.register_event_listener(listener)
+    try:
+        text = _smoke_chunk_for_v5e(one_chip, fused=True, arch="deepseek-v2-lite-16b-ep8",
+                                    nodes=3, min_fused=1 << 14)
+    finally:
+        jax.monitoring.unregister_event_listener(listener)
+    dispatch = [kw for event, kw in events if event == moe.DISPATCH_EVENT]
+    assert dispatch and {kw["held"] for kw in dispatch} == {2}
+    assert {(kw["router"], kw["top_k"], kw["row_bound"]) for kw in dispatch} == {(4, 2, 128)}
+    fused = [kw for event, kw in events if event == pme.FUSED_MASK_EVENT]
+    expert = 3 * 3 * 1 * 2 * 128 * 64  # w_gate, w_up, w_down of 3 nodes
+    assert fused and all(kw["coordinates"] >= expert for kw in fused)
+    kernels = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
+    assert {k.split(".")[0] for k in kernels} >= {"gmm", "tgmm"}
+    mapping = scopes.scope_map(text)
+    paths = {p for p in mapping.values() if p is not None}
+    assert set(scopes.SCOPES) == {name for p in paths for name in p}
+    for name in MOE_SCOPES:
+        assert re.search(r'op_name="[^"]*pame\.local_step[^"]*' + re.escape(name), text), name
+    work = _work(text)
+    mapped = sum(mapping[inst] is not None for inst in work)
+    assert mapped >= 0.95 * len(work), (mapped, len(work))
+    # the benchmark's MoE probe names the kernels by the model's scopes
+    from bench import cells
+
+    probe = cells.Suite().module("probes", "moe_scopes")
+    moe_map = probe.scope_map(text)
+    assert set(probe.NAMES) <= set(moe_map.values())
+    assert {moe_map[k] for k in kernels if k.startswith(("gmm", "tgmm"))} == {"moe.experts"}
