@@ -18,7 +18,9 @@ dispatch inside a single `jax.lax.scan`:
     frozen (`jnp.where` select per leaf), so the returned state is exactly
     the state at the triggering step even though the chunk runs to its
     static length.  The host only inspects a single boolean per chunk
-    boundary to decide whether to dispatch the next chunk.
+    boundary to decide whether to dispatch the next chunk.  Without an
+    objective there is no rule, and the chunk has neither window nor
+    selects: each step writes the carried state once.
 
 `make_scan_runner` returns a closure with a *persistent* jit cache: build
 the runner once per (step_fn, objective_fn, chunk_size) combination, warm
@@ -34,11 +36,12 @@ as a single non-scanned operand instead of stacking `chunk_size` copies.
 What a chunk does is named for the profiler: on the host, each chunk is an
 ``engine.chunk`` step span (``step_num`` = its first step) holding
 ``engine.batches``, ``engine.stack``, ``engine.dispatch`` and
-``engine.readback``; on the device, the scan body's termination window,
-freeze selects and per-step outputs run under the ``engine.carry`` scope.
-Each new chunk program records the ``CHUNK_BUILD_EVENT`` monitoring event,
-and the runner's ``optimized_hlo()`` gives the compiled text of the chunk
-programs it has run, whose instruction names a device trace uses.
+``engine.readback``; on the device, the stop rule's objective, window and
+freeze selects run under the ``engine.carry`` scope, which a chunk without
+a stop rule does not have.  Each new chunk program records the
+``CHUNK_BUILD_EVENT`` monitoring event, and the runner's ``optimized_hlo()``
+gives the compiled text of the chunk programs it has run, whose
+instruction names a device trace uses.
 """
 from __future__ import annotations
 
@@ -59,6 +62,7 @@ DEFAULT_CHUNK_SIZE = 32
 
 # jax.monitoring event recorded each time a runner builds a new chunk
 # program (a new (length, const_batch) pair), with both as keyword values
+# and ``stop_rule`` (1 when the chunk compiles the freeze selects, else 0)
 CHUNK_BUILD_EVENT = "/repro/engine/chunk_build"
 
 
@@ -192,6 +196,12 @@ def make_scan_runner(
     chunk executables are cached on the runner, so repeat runs with the
     same shapes skip compilation.
 
+    The stop rule, and with it the per-leaf freeze select over state and
+    aux, is compiled only when ``objective_fn`` is given.  Without one the
+    chunk carries the step's output as it is, so the step's update writes
+    the state once a round instead of feeding a select that always picks
+    the new state; the results are the same bit for bit.
+
     ``lanes=L`` turns the runner into the vmap-over-lanes batched engine:
     ``step_fn`` is expected to be lane-batched (state leaves ``[L, m,
     ...]``, per-step metric values of shape ``[L]`` — see
@@ -238,24 +248,25 @@ def make_scan_runner(
         else:
             new_state, metrics = step_fn(*step_args)
             new_aux = carry.aux
+        ys = dict(metrics)
+        if objective_fn is None:
+            # nothing can stop the run, so nothing is frozen: the carry takes
+            # the step's output as it is, and `done` stays false
+            ys["_stopped"] = carry.done
+            return carry._replace(state=new_state, aux=new_aux), ys
         with jax.named_scope("engine.carry"):
-            if objective_fn is not None:
-                # node axis is 0 for single runs, 1 behind the lane axis
-                mean_params = jax.tree_util.tree_map(
-                    lambda x: x.mean(axis=0 if lanes is None else 1),
-                    params_of(new_state),
-                )
-                obj_fn = objective_fn if lanes is None else jax.vmap(objective_fn)
-                obj = obj_fn(mean_params).astype(jnp.float32)  # [] or [L]
-                win = jnp.concatenate([carry.win[..., 1:], obj[..., None]], -1)
-                # guard on steps into *this run* (k_rel), not the global index:
-                # each run() starts a fresh zero window, and a k_start > 0 run
-                # must still fill all three slots before the rule can fire.
-                trigger = (k_rel >= 2) & (jnp.std(win, axis=-1) < tol_std)
-            else:
-                obj = None
-                win = carry.win
-                trigger = jnp.zeros((() if lanes is None else (lanes,)), bool)
+            # node axis is 0 for single runs, 1 behind the lane axis
+            mean_params = jax.tree_util.tree_map(
+                lambda x: x.mean(axis=0 if lanes is None else 1),
+                params_of(new_state),
+            )
+            obj_fn = objective_fn if lanes is None else jax.vmap(objective_fn)
+            obj = obj_fn(mean_params).astype(jnp.float32)  # [] or [L]
+            win = jnp.concatenate([carry.win[..., 1:], obj[..., None]], -1)
+            # guard on steps into *this run* (k_rel), not the global index:
+            # each run() starts a fresh zero window, and a k_start > 0 run
+            # must still fill all three slots before the rule can fire.
+            trigger = (k_rel >= 2) & (jnp.std(win, axis=-1) < tol_std)
             # A step that runs *after* the rule fired is a no-op: keep the frozen
             # state so the returned state is exactly the triggering step's (per
             # lane, when batched).
@@ -264,9 +275,7 @@ def make_scan_runner(
             out_aux = _tree_select(frozen, carry.aux, new_aux)
             out_win = _sel(frozen, carry.win, win)
             done = carry.done | trigger
-            ys = dict(metrics)
-            if obj is not None:
-                ys["objective"] = obj
+            ys["objective"] = obj
             ys["_stopped"] = done
             return _Carry(out_state, done, out_win, out_aux), ys
 
@@ -277,7 +286,8 @@ def make_scan_runner(
         key = (length, const_batch)
         if key not in compiled:
             jax.monitoring.record_event(
-                CHUNK_BUILD_EVENT, length=length, const_batch=int(const_batch)
+                CHUNK_BUILD_EVENT, length=length, const_batch=int(const_batch),
+                stop_rule=int(objective_fn is not None),
             )
 
             def chunk(carry, batch, k0, r0):

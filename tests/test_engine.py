@@ -287,3 +287,59 @@ def test_setup_compilation_cache(tmp_path, monkeypatch):
     finally:
         jax.config.update("jax_compilation_cache_dir", prior)
         engine.reset_cache()
+
+
+def _noisy_step(state, batch, aux=None):
+    """A nonlinear step with a PRNG draw per step; with ``aux`` it also
+    reads and rewrites a carried copy of the last parameters."""
+    key, sub = jax.random.split(state["key"])
+    w = state["w"]
+    g = jnp.tanh(w @ batch) @ batch.T + 0.1 * jax.random.normal(sub, w.shape)
+    if aux is not None:
+        g = g + 0.5 * (w - aux["prev"])
+    new = {"w": w - 0.05 * g, "key": key}
+    metrics = {"loss_mean": jnp.mean(g**2), "norm": jnp.sum(w**2)}
+    if aux is None:
+        return new, metrics
+    return new, metrics, {"prev": w, "t": aux["t"] + 1}
+
+
+@pytest.mark.parametrize("carries_aux", [False, True], ids=["state", "aux"])
+@pytest.mark.parametrize("lanes", [None, 2], ids=["single", "lanes2"])
+def test_no_stop_rule_matches_a_rule_that_never_fires(lanes, carries_aux):
+    """Without an objective the chunk has no freeze select; what it returns
+    is bit for bit what a runner with a rule that cannot fire (tol_std=0)
+    returns: final state, aux, every metric and info."""
+    from repro.core.engine import make_scan_runner
+
+    m, n = 4, 6
+    rng = np.random.default_rng(0)
+    w = jnp.asarray(rng.standard_normal((m, n)), jnp.float32)
+    state = {"w": w, "key": jax.random.PRNGKey(3)}
+    step = _noisy_step if carries_aux else (lambda s, b: _noisy_step(s, b))
+    if lanes is not None:
+        state = {"w": jnp.stack([w, -w]),
+                 "key": jax.random.split(jax.random.PRNGKey(3), lanes)}
+        step = jax.vmap(step, in_axes=(0, None) + ((0,) if carries_aux else ()))
+    aux = None
+    if carries_aux:
+        aux = {"prev": jnp.zeros_like(state["w"]),
+               "t": jnp.zeros(() if lanes is None else (lanes,), jnp.int32)}
+    batch = jnp.asarray(rng.standard_normal((n, 5)), jnp.float32)
+    outs = []
+    for objective_fn in (None, lambda p: jnp.sum(p**2)):
+        runner = make_scan_runner(
+            step, objective_fn=objective_fn, params_of=lambda s: s["w"],
+            tol_std=0.0, chunk_size=4, carries_aux=carries_aux, lanes=lanes,
+        )
+        outs.append(runner(state, lambda k: batch, 10, aux=aux))
+    (st0, met0, info0), (st1, met1, info1) = outs
+    leaves = lambda t: [np.asarray(x) for x in jax.tree_util.tree_leaves(t)]
+    for a, b in zip(leaves((st0, info0["aux"])), leaves((st1, info1["aux"]))):
+        np.testing.assert_array_equal(a, b)
+    assert set(met1) == set(met0) | {"objective"}
+    for key, val in met0.items():
+        np.testing.assert_array_equal(val, met1[key])
+    np.testing.assert_array_equal(info0["steps_run"], info1["steps_run"])
+    np.testing.assert_array_equal(info0["steps_run"], 10)
+    assert info0["steps_dispatched"] == info1["steps_dispatched"] == 10

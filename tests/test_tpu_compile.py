@@ -114,12 +114,13 @@ BOOKKEEPING_CALLS = ("AllocateBuffer", "ConcatBitcast")
 
 
 def _smoke_chunk_for_v5e(one_chip, fused: bool = False, arch: str = "stablelm-1.6b",
-                         nodes: int = 4, min_fused: int = None) -> str:
-    """The optimized HLO of the bound PaME step's scan chunk at smoke widths
-    (dense mixing, bernoulli masks, the CLI's hyperparameters) compiled for
-    a v5e; ``fused`` takes the gate of the fused bernoulli kernel as on a
-    TPU (for leaves of ``min_fused`` coordinates or more where given), and
-    the MoE kernels compiled rather than interpreted.  The chunk runs on
+                         nodes: int = 4, min_fused: int = None, objective_fn=None):
+    """The bound PaME step's scan chunk at smoke widths (dense mixing,
+    bernoulli masks, the CLI's hyperparameters) compiled for a v5e;
+    ``fused`` takes the gate of the fused bernoulli kernel as on a TPU (for
+    leaves of ``min_fused`` coordinates or more where given), and the MoE
+    kernels compiled rather than interpreted; ``objective_fn`` gives the
+    runner a stop rule that never fires (``tol_std`` 0).  The chunk runs on
     the CPU first, for its arguments."""
     from repro.core import engine, pme
     from repro.kernels.pme_average import ops
@@ -130,7 +131,8 @@ def _smoke_chunk_for_v5e(one_chip, fused: bool = False, arch: str = "stablelm-1.
         "--arch", arch, "--variant", "smoke", "--nodes", str(nodes),
         "--mixing", "dense", "--batch", "2", "--seq", "32", "--chunk", "4"])
     _, bound, state, make_batch, _, _ = train.build_everything(args)
-    run = engine.make_scan_runner(bound.step, chunk_size=4)
+    run = engine.make_scan_runner(bound.step, chunk_size=4,
+                                  objective_fn=objective_fn, tol_std=0.0)
     run(state, make_batch, 4, copy_state=False)  # on the CPU, for the arguments
     (chunk, arguments), = run.chunk_programs().values()
     on_chip = jax.tree_util.tree_map(
@@ -143,7 +145,7 @@ def _smoke_chunk_for_v5e(one_chip, fused: bool = False, arch: str = "stablelm-1.
             if min_fused is not None:
                 mp.setattr(pme, "_KERNEL_MIN_ELEMS", min_fused)
             jax.clear_caches()  # trace the chunk again, through the gate
-        return chunk.lower(*on_chip).compile().as_text()
+        return chunk.lower(*on_chip).compile()
 
 
 def _work(text: str) -> list:
@@ -174,15 +176,15 @@ def bench_importable():
 
 
 def test_pame_chunk_is_named_by_scope_for_v5e(one_chip, bench_importable):
-    """Every scope of the round appears in the compiled chunk, and at least
-    95% of the fusions and custom calls outside fused computations map to
-    one."""
+    """Every scope of the round appears in the compiled chunk, but the stop
+    rule's (the run has no objective), and at least 95% of the fusions and
+    custom calls outside fused computations map to one."""
     from bench import scopes
 
-    text = _smoke_chunk_for_v5e(one_chip)
+    text = _smoke_chunk_for_v5e(one_chip).as_text()
     mapping = scopes.scope_map(text)
     paths = {p for p in mapping.values() if p is not None}
-    assert set(scopes.SCOPES) == {name for p in paths for name in p}
+    assert set(scopes.SCOPES) - {"engine.carry"} == {name for p in paths for name in p}
     work = _work(text)
     mapped = sum(mapping[inst] is not None for inst in work)
     assert len(work) > 100 and mapped >= 0.95 * len(work), (mapped, len(work))
@@ -202,7 +204,7 @@ def test_pame_chunk_with_fused_masks_is_named_by_scope_for_v5e(one_chip, bench_i
     listener = lambda event, **kw: events.append(kw) if event == pme.FUSED_MASK_EVENT else None
     jax.monitoring.register_event_listener(listener)
     try:
-        text = _smoke_chunk_for_v5e(one_chip, fused=True)
+        text = _smoke_chunk_for_v5e(one_chip, fused=True).as_text()
     finally:
         jax.monitoring.unregister_event_listener(listener)
     assert {e["leaves"] for e in events} == {8}
@@ -211,10 +213,26 @@ def test_pame_chunk_with_fused_masks_is_named_by_scope_for_v5e(one_chip, bench_i
     assert len(kernels) == 8
     assert {mapping[k] for k in kernels} == {("pame.exchange", "pme.average")}
     paths = {p for p in mapping.values() if p is not None}
-    assert set(scopes.SCOPES) == {name for p in paths for name in p}
+    assert set(scopes.SCOPES) - {"engine.carry"} == {name for p in paths for name in p}
     work = _work(text)
     mapped = sum(mapping[inst] is not None for inst in work)
     assert mapped >= 0.95 * len(work), (mapped, len(work))
+
+
+def test_stop_rule_chunk_keeps_its_selects_and_their_scratch_for_v5e(
+        one_chip, bench_importable):
+    """Under an objective (one that never fires) the chunk compiles the
+    freeze selects under ``engine.carry``, and they cost scratch that the
+    chunk without a stop rule does not need."""
+    from bench import scopes
+
+    plain = _smoke_chunk_for_v5e(one_chip)
+    ruled = _smoke_chunk_for_v5e(
+        one_chip, objective_fn=lambda params: jnp.zeros((), jnp.float32))
+    mapping = scopes.scope_map(ruled.as_text())
+    assert "engine.carry" in {name for p in mapping.values() if p for name in p}
+    assert (plain.memory_analysis().temp_size_in_bytes
+            < ruled.memory_analysis().temp_size_in_bytes)
 
 
 MOE_SCOPES = ("moe.route", "moe.experts", "moe.shared", "moe.combine", "mla.attend")
@@ -242,7 +260,7 @@ def test_moe_share_chunk_is_named_by_scope_for_v5e(one_chip, bench_importable):
     jax.monitoring.register_event_listener(listener)
     try:
         text = _smoke_chunk_for_v5e(one_chip, fused=True, arch="deepseek-v2-lite-16b-ep8",
-                                    nodes=3, min_fused=1 << 14)
+                                    nodes=3, min_fused=1 << 14).as_text()
     finally:
         jax.monitoring.unregister_event_listener(listener)
     dispatch = [kw for event, kw in events if event == moe.DISPATCH_EVENT]
@@ -255,7 +273,7 @@ def test_moe_share_chunk_is_named_by_scope_for_v5e(one_chip, bench_importable):
     assert {k.split(".")[0] for k in kernels} >= {"gmm", "tgmm"}
     mapping = scopes.scope_map(text)
     paths = {p for p in mapping.values() if p is not None}
-    assert set(scopes.SCOPES) == {name for p in paths for name in p}
+    assert set(scopes.SCOPES) - {"engine.carry"} == {name for p in paths for name in p}
     for name in MOE_SCOPES:
         assert re.search(r'op_name="[^"]*pame\.local_step[^"]*' + re.escape(name), text), name
     work = _work(text)

@@ -6,20 +6,23 @@ import re
 
 import jax
 import jax.numpy as jnp
+import pytest
 
 from repro.core import engine
 from repro.launch import train
 
 SCOPES = ("pame.select", "pame.exchange", "pame.local_step", "pame.update",
-          "pame.metrics", "pme.mask", "pme.average", "engine.carry")
+          "pame.metrics", "pme.mask", "pme.average")
+STOP_RULE_SCOPE = "engine.carry"  # only in chunks built with an objective
 ENGINE_SPANS = ("engine.batches", "engine.stack", "engine.dispatch", "engine.readback")
 
 
-def _counting_runner(chunk_size=4):
+def _counting_runner(chunk_size=4, objective_fn=None):
     def step(state, batch):
         return state + batch, {"s": jnp.sum(state)}
 
-    return engine.make_scan_runner(step, chunk_size=chunk_size, params_of=lambda s: s)
+    return engine.make_scan_runner(step, chunk_size=chunk_size, params_of=lambda s: s,
+                                   objective_fn=objective_fn, tol_std=0.0)
 
 
 class Builds:
@@ -67,7 +70,31 @@ def test_optimized_hlo_is_keyed_like_the_cache_and_compiles_nothing_new():
     assert "/jax/core/compile/backend_compile_duration" not in compiles
     assert set(texts) == {(4, True), (2, True)}
     assert all(t.startswith("HloModule jit_chunk") for t in texts.values())
-    assert "engine.carry" in texts[(4, True)]
+    assert STOP_RULE_SCOPE not in texts[(4, True)]
+
+
+def test_chunk_build_says_whether_it_compiles_the_stop_rule():
+    rules = []
+    listener = (lambda event, **kw: rules.append(kw["stop_rule"])
+                if event == engine.CHUNK_BUILD_EVENT else None)
+    jax.monitoring.register_event_listener(listener)
+    try:
+        _counting_runner()(jnp.zeros((3,)), lambda k: jnp.ones((3,)), 4)
+        _counting_runner(objective_fn=jnp.sum)(jnp.zeros((3,)), lambda k: jnp.ones((3,)), 4)
+    finally:
+        jax.monitoring.unregister_event_listener(listener)
+    assert rules == [0, 1]
+
+
+@pytest.mark.parametrize("objective_fn", [None, jnp.sum], ids=["no_rule", "rule"])
+def test_only_a_chunk_with_a_stop_rule_has_engine_carry(objective_fn):
+    """The window and freeze selects are compiled, under their scope, only
+    where an objective can stop the run (here one that never fires)."""
+    run = _counting_runner(objective_fn=objective_fn)
+    run(jnp.zeros((3,)), lambda k: jnp.ones((3,)), 4)
+    (text,) = run.optimized_hlo().values()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    assert any(STOP_RULE_SCOPE in n for n in names) == (objective_fn is not None)
 
 
 def test_engine_spans_nest_under_their_chunk_with_its_first_step(tmp_path):
@@ -108,7 +135,8 @@ def _smoke_args(*extra):
 
 def test_pame_chunk_names_every_scope_of_the_round():
     """On the CPU too: the optimized chunk of the bound PaME step carries
-    each scope of the set in its instructions' op_name metadata."""
+    each scope of the set in its instructions' op_name metadata, and no
+    stop rule's, since the run has no objective."""
     cfg, bound, state, make_batch, _, _ = train.build_everything(_smoke_args())
     run = engine.make_scan_runner(bound.step, chunk_size=4)
     run(state, make_batch, 4, copy_state=False)
@@ -117,6 +145,7 @@ def test_pame_chunk_names_every_scope_of_the_round():
     for scope in SCOPES:
         assert any(re.search(r"(?<![\w.])" + re.escape(scope) + r"(?![\w.])", n)
                    for n in names), scope
+    assert not any(STOP_RULE_SCOPE in n for n in names)
 
 
 def test_train_log_gives_the_chunk_time_and_names_each_build(capsys):
