@@ -1,33 +1,14 @@
-"""§Roofline: three-term analysis from the dry-run artifacts.
+"""Bytes-moved / FLOP roofline terms for the gossip contraction race.
 
-    compute term    = HLO_FLOPs_per_device / peak_FLOPs_per_chip
-    memory term     = HLO_bytes_per_device / HBM_bw_per_chip
-    collective term = collective_bytes_per_device / link_bw
-
-Hardware: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
-
-XLA's HloCostAnalysis counts a while-loop (lax.scan) body ONCE, so the
-full-depth numbers undercount the layer stack.  Two reduced-depth UNROLLED
-probes per combo give the exact marginal per-layer cost; we extrapolate
-linearly to the real depth:
-
-    X(L) = X(a) + (L - a) * (X(b) - X(a)) / (b - a)
-
-MODEL_FLOPS = 6 * N * D (dense; N_active for MoE) is reported alongside and
-the ratio MODEL_FLOPS / HLO_FLOPS flags remat/redundancy waste.
+Hardware: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM.  `bench_gossip`
+prices each `gather_terms` implementation with `gossip_roofline`.
 """
 from __future__ import annotations
 
-import json
-import os
-from typing import Dict, List, Optional
+from typing import Optional
 
 PEAK_FLOPS = 197e12      # bf16 per chip
 HBM_BW = 819e9           # bytes/s per chip
-LINK_BW = 50e9           # bytes/s per link (ICI)
-
-HERE = os.path.dirname(os.path.abspath(__file__))
-DRYRUN_PATH = os.path.join(HERE, "artifacts", "dryrun.json")
 
 
 def gossip_roofline(
@@ -89,117 +70,3 @@ def gossip_roofline(
         row["us"] = measured_us
         row["achieved_gbps"] = hbm / (measured_us * 1e-6) / 1e9
     return row
-
-
-def load_results(path: str = DRYRUN_PATH) -> Dict[str, dict]:
-    with open(path) as f:
-        return json.load(f)
-
-
-def _extrapolate(full: dict, pa: Optional[dict], pb: Optional[dict]) -> dict:
-    """Correct scan-undercounted metrics using the two unrolled probes."""
-    l_target = full["n_layers"]
-    out = dict(full)
-    if not pa or not pb:
-        out["extrapolated"] = False
-        return out
-    a, b = pa["n_layers"], pb["n_layers"]
-    if b == a:
-        out["extrapolated"] = False
-        return out
-
-    def lin(metric):
-        xa, xb = pa[metric], pb[metric]
-        return max(xa + (l_target - a) * (xb - xa) / (b - a), xa)
-
-    out["flops_per_device"] = lin("flops_per_device")
-    out["bytes_per_device"] = lin("bytes_per_device")
-    ca = pa["collective_bytes_total"]
-    cb = pb["collective_bytes_total"]
-    out["collective_bytes_total"] = max(ca + (l_target - a) * (cb - ca) / (b - a), ca)
-    out["extrapolated"] = True
-    return out
-
-
-def roofline_row(rec: dict) -> dict:
-    t_compute = rec["flops_per_device"] / PEAK_FLOPS
-    t_memory = rec["bytes_per_device"] / HBM_BW
-    t_coll = rec["collective_bytes_total"] / LINK_BW
-    terms = {"compute": t_compute, "memory": t_memory, "collective": t_coll}
-    dominant = max(terms, key=terms.get)
-    chips = rec["layout"]["node"] * rec["layout"]["fsdp"] * rec["layout"]["model"]
-    # model flops for this step (per device): 6 N D tokens, x3 for bwd in train
-    n_active = rec["active_param_count"]
-    mult = 3.0 if rec["shape"].startswith("train") else 1.0
-    model_flops_total = 2.0 * n_active * rec["tokens"] * mult
-    model_flops_dev = model_flops_total / chips
-    ratio = model_flops_dev / rec["flops_per_device"] if rec["flops_per_device"] else 0.0
-    return {
-        "arch": rec["arch"],
-        "shape": rec["shape"],
-        "mesh": rec["mesh"],
-        "layout": rec["layout"],
-        "t_compute_s": t_compute,
-        "t_memory_s": t_memory,
-        "t_collective_s": t_coll,
-        "dominant": dominant,
-        "model_flops_per_device": model_flops_dev,
-        "hlo_flops_per_device": rec["flops_per_device"],
-        "useful_ratio": ratio,
-        "extrapolated": rec.get("extrapolated", False),
-        "temp_gb": rec["memory"]["temp_bytes"] / 1e9,
-    }
-
-
-def build_table(results: Optional[Dict[str, dict]] = None, mesh: str = "single") -> List[dict]:
-    res = results or load_results()
-    rows = []
-    for key, rec in sorted(res.items()):
-        if rec.get("probe_layers") is not None or rec["mesh"] != mesh:
-            continue
-        if rec.get("variant", "baseline") != "baseline":
-            continue  # §Perf variants are reported separately
-        arch, shape = rec["arch"], rec["shape"]
-        pa = res.get(f"{arch}|{shape}|{mesh}|L{_depths(rec)[0]}")
-        pb = res.get(f"{arch}|{shape}|{mesh}|L{_depths(rec)[1]}")
-        rows.append(roofline_row(_extrapolate(rec, pa, pb)))
-    return rows
-
-
-def _depths(rec: dict) -> tuple:
-    from repro.configs import get_config
-    from repro.launch.dryrun import probe_depths
-
-    return probe_depths(get_config(rec["arch"]))
-
-
-def format_table(rows: List[dict]) -> str:
-    hdr = (
-        f"{'arch':22s} {'shape':12s} {'n/f/m':9s} "
-        f"{'compute(s)':>11s} {'memory(s)':>11s} {'collect(s)':>11s} "
-        f"{'dominant':>10s} {'useful':>7s} {'temp GB':>8s}"
-    )
-    lines = [hdr, "-" * len(hdr)]
-    for r in rows:
-        lay = r["layout"]
-        lines.append(
-            f"{r['arch']:22s} {r['shape']:12s} "
-            f"{lay['node']}/{lay['fsdp']}/{lay['model']:<5d} "
-            f"{r['t_compute_s']:11.4g} {r['t_memory_s']:11.4g} "
-            f"{r['t_collective_s']:11.4g} {r['dominant']:>10s} "
-            f"{r['useful_ratio']:7.2f} {r['temp_gb']:8.2f}"
-        )
-    return "\n".join(lines)
-
-
-def main() -> None:
-    rows = build_table()
-    print(format_table(rows))
-    out = os.path.join(HERE, "artifacts", "roofline.json")
-    with open(out, "w") as f:
-        json.dump(rows, f, indent=1)
-    print(f"\nwrote {out} ({len(rows)} rows)")
-
-
-if __name__ == "__main__":
-    main()

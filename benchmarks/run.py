@@ -37,7 +37,6 @@ Figure map:
   bench_comm_volume        Eq. (8)      (bit accounting, 64/16/8-bit wires)
   bench_kernels            —            (Pallas kernels, interpret-mode checks)
   bench_engine             —            (host-loop vs scan-driver us_per_call)
-  bench_roofline           —            (§Roofline table from the dry-run)
 """
 from __future__ import annotations
 
@@ -45,7 +44,7 @@ import argparse
 import json
 import os
 import time
-from typing import Dict, List
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
@@ -1311,7 +1310,7 @@ def bench_engine(quick=False):
 
 def bench_comm_volume(quick=False):
     """Eq. (8): bits per message, sparse vs dense; 64-/16-bit float payloads
-    plus the int8 wire of exchange="compressed_q8"."""
+    plus an int8 wire."""
     table = {}
     for n in (10_000, 100_000, 1_000_000):
         for frac in (0.01, 0.1, 0.2):
@@ -1384,26 +1383,6 @@ def bench_kernels(quick=False):
     table["ssd_scan"] = {"us_kernel": us_k, "us_ref": us_r, "max_err": err}
     csv_row("kernels/ssd_scan", us_k, f"ref_us={us_r:.1f};max_err={err:.2e}")
     RESULTS["kernels"] = table
-
-
-def bench_roofline(quick=False):
-    """§Roofline table (single-pod baselines for all 40 pairs)."""
-    from benchmarks import roofline
-
-    try:
-        rows = roofline.build_table()
-    except FileNotFoundError:
-        csv_row("roofline", 0.0, "SKIPPED=no dryrun.json; run repro.launch.dryrun first")
-        return
-    print(roofline.format_table(rows))
-    for r in rows:
-        csv_row(
-            f"roofline/{r['arch']}/{r['shape']}", 0.0,
-            f"compute_s={r['t_compute_s']:.4g};memory_s={r['t_memory_s']:.4g};"
-            f"collective_s={r['t_collective_s']:.4g};dominant={r['dominant']};"
-            f"useful={r['useful_ratio']:.2f}",
-        )
-    RESULTS["roofline"] = rows
 
 
 def bench_serving(quick=False):
@@ -1692,7 +1671,6 @@ BENCHES = {
     "comm_volume": bench_comm_volume,
     "kernels": bench_kernels,
     "engine": bench_engine,
-    "roofline": bench_roofline,
 }
 
 

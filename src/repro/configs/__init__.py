@@ -3,7 +3,7 @@ and the chip shares of them that a deployment divides over chips.
 
 Every module exposes FULL (exact assigned config) and SMOKE (reduced:
 <=2 layers, d_model <= 512, <=4 experts) ModelConfigs.  `get_config(name,
-variant)` is the single lookup used by the launcher, dry-run and tests.
+variant)` is the single lookup used by the launcher, benchmarks and tests.
 """
 from __future__ import annotations
 

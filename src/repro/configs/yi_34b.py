@@ -1,7 +1,6 @@
 """yi-34b [dense] — llama-arch GQA [arXiv:2403.04652].
 
 60L d_model=7168 56H (GQA kv=8, head_dim=128) d_ff=20480 vocab=64000.
-Big-model node layout: fsdp > 1 (see repro.sharding).
 """
 from repro.models.config import ModelConfig
 

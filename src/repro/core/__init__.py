@@ -9,7 +9,6 @@
   scenarios    — dynamic networks: per-step link churn, dropout, stragglers
   temporal     — Markov link/node processes + bounded-staleness gossip
   compression  — rand-k / top-k / QSGD / one-bit operators
-  gossip       — mesh-sharded gossip (dense-masked + compressed payload)
 """
 from repro.core.topology import Topology, build_topology  # noqa: F401
 from repro.core.mixing import (  # noqa: F401

@@ -160,11 +160,11 @@ class Algorithm:
     # scalar itself entering the trace — the stacked per-config extras
     # carry the difference.
     setup_hp_fields: Tuple[str, ...] = ()
-    # optional (hps) -> bool: the step consumes the delayed-delivery
-    # extras itself (``fresh_params`` fresh self-view + ``delivered``
-    # message masks) instead of the wrapper's post-hoc innovation re-add
-    # — PaME's memoryless exchange needs no mean bookkeeping.
-    handles_delay: Optional[Callable] = None
+    # the step consumes the delayed-delivery extras itself
+    # (``fresh_params`` fresh self-view + ``delivered`` message masks)
+    # instead of the wrapper's post-hoc innovation re-add — PaME's
+    # memoryless exchange needs no mean bookkeeping.
+    handles_delay: bool = False
     # optional replicated variants for fault-injected binds (surrogate-
     # memory algorithms): ``rep_init(key, stacked, ctx, batch0, arrays)``
     # and ``rep_step(state, batch, ctx)`` reading the FaultRealization
@@ -657,10 +657,10 @@ class BoundAlgorithm:
         the self-view ``B_jj·fresh_j + (1−B_jj)·(fresh_j − eff_j)`` on
         top of the off-diagonal terms: exactly the fresh self-view plus
         the (1−B_jj)-scaled innovation correction that restores the
-        global parameter sum for every realized matrix.  Algorithms whose
-        ``handles_delay(hps)`` is true (PaME's dense exchange) instead
-        consume the fresh stack directly (``fresh_params`` extra → the
-        lambda=0 / uncovered-coordinate fallback) and skip the re-add —
+        global parameter sum for every realized matrix.  Algorithms
+        registered with ``handles_delay`` (PaME) instead consume the
+        fresh stack directly (``fresh_params`` extra → the lambda=0 /
+        uncovered-coordinate fallback) and skip the re-add —
         their exchange is memoryless, so there is no surrogate mean to
         rebalance.  Requires the algorithm state to carry its
         node-stacked parameters in a ``params`` field (all built-in
@@ -671,8 +671,6 @@ class BoundAlgorithm:
         )
         mixer = scen_mod.scenario_mixer(self.scen_arrays, r, self._mixing_mode)
         extras = {**self.ctx.extras, "realization": r}
-        hd = (self.spec.handles_delay is not None
-              and self.spec.handles_delay(self.ctx.hps))
         d_max = self.scenario.staleness
         ring = aux.ring
         if d_max > 0:
@@ -680,7 +678,7 @@ class BoundAlgorithm:
             slot = jnp.mod(k - tau, d_max)
             eff = ring_gather(ring, fresh, slot, delayed)
             state_in = state._replace(params=eff)
-            if hd:
+            if self.spec.handles_delay:
                 extras["fresh_params"] = fresh
             else:
                 # zero rows for punctual nodes: every gradient call point
@@ -693,7 +691,7 @@ class BoundAlgorithm:
         ctx_t = dataclasses.replace(self.ctx, mixer=mixer, extras=extras)
         new_state, metrics = self.spec.step(state_in, batch, ctx_t)
         if d_max > 0:
-            if not hd:
+            if not self.spec.handles_delay:
                 def _readd(p, f, e):
                     keep = delayed.reshape((-1,) + (1,) * (p.ndim - 1))
                     return p + jnp.where(keep, f - e, jnp.zeros_like(p))
@@ -757,8 +755,6 @@ class BoundAlgorithm:
         extras = {**self.ctx.extras, "realization": r, "fault": fr,
                   "fault_arrays": self.scen_arrays,
                   "delivered": fr.recv_ok, "repair": fm.repair}
-        hd = (self.spec.handles_delay is not None
-              and self.spec.handles_delay(self.ctx.hps))
         d_max = fm.max_delay
         ring = aux.ring
         if d_max > 0:
@@ -766,7 +762,7 @@ class BoundAlgorithm:
             slot = jnp.mod(k - fr.tau, d_max)
             eff = ring_gather(ring, fresh, slot, fr.delayed)
             state_in = state._replace(params=eff)
-            if hd:
+            if self.spec.handles_delay:
                 extras["fresh_params"] = fresh
             else:
                 extras["grad_shift"] = jax.tree_util.tree_map(
@@ -785,7 +781,7 @@ class BoundAlgorithm:
         step_fn = self.spec.rep_step if use_rep else self.spec.step
         new_state, metrics = step_fn(state_in, batch, ctx_t)
         if d_max > 0:
-            if not hd:
+            if not self.spec.handles_delay:
                 def _readd(p, f, e):
                     keep = fr.delayed.reshape((-1,) + (1,) * (p.ndim - 1))
                     return p + jnp.where(keep, f - e, jnp.zeros_like(p))
@@ -1297,12 +1293,11 @@ def _pame_msgs_per_step(topo: Topology, hps: PaMEHp) -> float:
 
 def _pame_wire_bits(topo: Topology, hps: PaMEHp, n: int) -> float:
     """Expected bits/step pricing one flat n-coordinate message of
-    message_bits(s, n) per transmission (int8 when exchange="compressed_q8").
-    The flat-partition formula; multi-leaf models route through
-    _pame_wire_bits_sizes wherever the leaf structure is known."""
+    message_bits(s, n) per transmission.  The flat-partition formula;
+    multi-leaf models route through _pame_wire_bits_sizes wherever the
+    leaf structure is known."""
     s = max(1, int(round(hps.p * n)))
-    value_bits = 8 if hps.exchange == "compressed_q8" else 64
-    return _pame_msgs_per_step(topo, hps) * message_bits(s, n, value_bits)
+    return _pame_msgs_per_step(topo, hps) * message_bits(s, n)
 
 
 def _pame_wire_bits_sizes(topo: Topology, hps: PaMEHp, sizes) -> float:
@@ -1311,11 +1306,8 @@ def _pame_wire_bits_sizes(topo: Topology, hps: PaMEHp, sizes) -> float:
     partition sums the per-leaf Eq.-(8) segments at their p_leaf rates."""
     if hps.partition != "tree":
         return _pame_wire_bits(topo, hps, sum(sizes))
-    value_bits = 8 if hps.exchange == "compressed_q8" else 64
     rates = pme_leaf_rates(len(sizes), hps.p, hps.p_leaf)
-    return _pame_msgs_per_step(topo, hps) * tree_message_bits(
-        sizes, rates, value_bits
-    )
+    return _pame_msgs_per_step(topo, hps) * tree_message_bits(sizes, rates)
 
 
 # ---------------------------------------------------------------------------
@@ -1345,19 +1337,17 @@ register(Algorithm(
     wire_bits_sizes=_pame_wire_bits_sizes,
     setup=_pame_setup,
     counts=True,
-    # dense-exchange PaME consumes message-only delay natively: senders
-    # transmit the ring-delayed stack while the lambda=0 / uncovered-
-    # coordinate fallback reads the fresh self-view — no innovation
-    # re-add (the count-normalized average is memoryless).  The
-    # compressed exchange paths keep the wrapper's re-add semantics.
-    handles_delay=lambda hps: hps.exchange == "dense",
+    # PaME consumes message-only delay natively: senders transmit the
+    # ring-delayed stack while the lambda=0 / uncovered-coordinate
+    # fallback reads the fresh self-view — no innovation re-add (the
+    # count-normalized average is memoryless).
+    handles_delay=True,
     # PaME's step emits its own realized "wire_bits" (per-message Eq. (8)
     # on the selected surviving neighbors), so no per-edge rate here.
     # p fixes the message payload size s = round(p·n) (shape-static);
     # nu / kappa_* are realized into TopologyArrays by setup, so batched
     # configs may sweep them without the scalars entering the trace.
-    static_hp_fields=("p", "mask_mode", "exchange", "mixing",
-                      "partition", "p_leaf"),
+    static_hp_fields=("p", "mask_mode", "mixing", "partition", "p_leaf"),
     setup_hp_fields=("nu", "kappa_lo", "kappa_hi", "homogeneous_kappa"),
 ))
 

@@ -50,11 +50,7 @@ class PaMEConfig:
     kappa_hi: int = 7
     mask_mode: str = "exact"  # "exact" (paper) | "bernoulli" (huge leaves)
     homogeneous_kappa: Optional[int] = None  # set to force kappa_i = k0
-    exchange: str = "dense"  # "dense" (paper-faithful simulation) |
-                             # "compressed" (block-systematic payloads, the
-                             # beyond-paper wire format — core.gossip) |
-                             # "compressed_q8" (int8 payloads on the wire)
-    mixing: str = "dense"    # node-axis contraction of the dense exchange:
+    mixing: str = "dense"    # node-axis contraction of the exchange:
                              # "dense" ([m, m] selection-matrix einsum) |
                              # "sparse" (padded neighbor gather, O(m·deg·n))
     partition: str = "flat"  # message format over a multi-leaf model:
@@ -79,11 +75,6 @@ class PaMEConfig:
             # static_hp_fields, which compares configs for equality
             object.__setattr__(
                 self, "p_leaf", tuple(float(r) for r in self.p_leaf)
-            )
-        if self.partition == "tree" and self.exchange != "dense":
-            raise NotImplementedError(
-                "partition='tree' needs exchange='dense'; the compressed "
-                "wire formats still assume a single flat payload"
             )
 
 
@@ -152,8 +143,6 @@ def pame_step(
     grad_fn: GradFn,
     topo: TopologyArrays,
     cfg: PaMEConfig,
-    param_shardings: Optional[object] = None,  # pin v_bar's layout so the
-    # gossip einsum cannot re-shard the whole model compute downstream
     realization: Optional[object] = None,  # scenarios.Realization — dynamic
     # network state for this step; restricts PME to surviving neighbors and
     # adds realized wire-bit metrics.  None keeps the static program as-is.
@@ -170,7 +159,7 @@ def pame_step(
     # the nodes, join the round's metrics (a MoE model's ``expert_rows``)
 ) -> Tuple[PaMEState, dict]:
     m = topo.nbrs.shape[0]
-    sparse = cfg.exchange == "dense" and cfg.mixing == "sparse"
+    sparse = cfg.mixing == "sparse"
     if cfg.partition == "tree":
         # tree-partitioned exchange: each leaf is its own message segment
         # with its own rate; a float keeps the flat code path bit-identical
@@ -183,11 +172,6 @@ def pame_step(
             "message-level delivery masks need mixing='sparse' "
             "(padded selection); the dense selection matrix has no "
             "per-slot delivery channel"
-        )
-    if cfg.exchange in ("compressed", "compressed_q8") and self_params is not None:
-        raise NotImplementedError(
-            "self_params (message-only delay) is not supported on "
-            "the compressed exchange path"
         )
 
     # The round's work is named by scope (pame.select, pame.exchange,
@@ -224,20 +208,11 @@ def pame_step(
                 k_mask, state.params, topo.nbrs, sel_recv, rate,
                 mode=cfg.mask_mode, pad=~topo.valid, self_params=self_params,
             )
-        elif cfg.exchange in ("compressed", "compressed_q8"):
-            from repro.core import gossip
-
-            v_bar = gossip.compressed_pme_average_pytree(
-                k_mask, state.params, a, cfg.p, shardings=param_shardings,
-                quantize_bits=8 if cfg.exchange == "compressed_q8" else 0,
-            )
         else:
             v_bar = pme.pme_average_pytree(
                 k_mask, state.params, a, rate, mode=cfg.mask_mode,
                 self_params=self_params,
             )
-        if param_shardings is not None:
-            v_bar = jax.lax.with_sharding_constraint(v_bar, param_shardings)
 
     with jax.named_scope("pame.local_step"):
         node_keys = jax.random.split(k_data, m)
@@ -278,22 +253,20 @@ def pame_step(
             metrics["senders_drawn"] = jnp.sum(jnp.any(a != 0, axis=1).astype(jnp.int32))
         if realization is not None:
             # realized Eq.-(8) accounting: each selected surviving neighbor
-            # transmits one sparse message, in the int8 wire format when
-            # exchange="compressed_q8".  Flat partition prices one
-            # concatenated vector of s = round(p·n_total) coordinates; tree
-            # partition sums the per-leaf segments (their own s_leaf +
-            # occupancy pattern each).
+            # transmits one sparse message of 64-bit values.  Flat partition
+            # prices one concatenated vector of s = round(p·n_total)
+            # coordinates; tree partition sums the per-leaf segments (their
+            # own s_leaf + occupancy pattern each).
             sizes = [
                 int(np.prod(leaf.shape[1:]))
                 for leaf in jax.tree_util.tree_leaves(state.params)
             ]
-            value_bits = 8 if cfg.exchange == "compressed_q8" else 64
             if cfg.partition == "tree":
-                bits = pme.tree_message_bits(sizes, rate, value_bits)
+                bits = pme.tree_message_bits(sizes, rate)
             else:
                 n_total = sum(sizes)
                 s = max(1, int(round(cfg.p * n_total)))
-                bits = pme.message_bits(s, n_total, value_bits)
+                bits = pme.message_bits(s, n_total)
             metrics["wire_bits"] = n_messages.astype(jnp.float32) * float(bits)
     return new_state, metrics
 
@@ -313,7 +286,6 @@ def make_pame_runner(
     tol_std: float = 1e-3,
     chunk_size: int = engine.DEFAULT_CHUNK_SIZE,
     seed: int = 0,
-    param_shardings: Optional[object] = None,
 ) -> Callable:
     """Build a reusable scan-fused PaME driver (see `repro.core.engine`).
 
@@ -324,8 +296,7 @@ def make_pame_runner(
     topo_arrays = make_topology_arrays(topo, cfg, seed=seed)
 
     def step_fn(state, batch):
-        return pame_step(state, batch, grad_fn, topo_arrays, cfg,
-                         param_shardings=param_shardings)
+        return pame_step(state, batch, grad_fn, topo_arrays, cfg)
 
     runner = engine.make_scan_runner(
         step_fn,

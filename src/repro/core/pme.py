@@ -12,10 +12,10 @@ Two mask samplers are provided:
                   where an argsort over n is wasteful).
 
 The aggregation itself is written as dense masked matmuls over the node
-axis — TPU-native (MXU) data movement; under GSPMD the node-axis einsums
-lower to all-gathers across the (pod, data) mesh axes.  A compressed
-payload path (values + PRNG seed instead of dense masked vectors) lives in
-`repro.core.gossip` and `repro.kernels.pme_average`.
+axis, with every node's leaves on one device.  On an accelerator, large
+leaves route through the fused Pallas kernels of
+`repro.kernels.pme_average`; under bernoulli masks the kernel draws each
+selected sender's mask in the same pass as the average.
 """
 from __future__ import annotations
 
@@ -304,8 +304,8 @@ def _average_leaf(leaf, masks, a, own, mode: str):
         else:
             avg = pme_average(flat, masks, a, own=own.reshape(m, -1))
         return avg.reshape(leaf.shape)
-    # No reshape: keep the leaf's trailing structure (and thus its
-    # tensor sharding) intact; only the node axis is contracted.
+    # No reshape: keep the leaf's trailing structure intact; only the
+    # node axis is contracted.
     # Operands stay in the leaf dtype (bf16 at model scale) with f32
     # accumulation — counts <= m are exactly representable.
     mask_t = masks.astype(leaf.dtype)
@@ -396,9 +396,9 @@ def message_bits(s: int, n: int, value_bits: int = 64) -> int:
     """Eq. (8): transmitting a sparse vector costs (value_bits-1)*s + n bits
     (s payload values + an n-bit occupancy pattern); 64-bit gives 63s + n.
 
-    value_bits=8 is the int8 wire format of exchange="compressed_q8": full
-    8-bit payload values (no sign-bit folding), the n-bit occupancy pattern,
-    plus one f32 absmax scale per message for dequantisation.
+    value_bits=8 prices an int8 wire: full 8-bit payload values (no
+    sign-bit folding), the n-bit occupancy pattern, plus one f32 absmax
+    scale per message for dequantisation.
     """
     if value_bits == 8:
         return 8 * s + n + 32

@@ -116,33 +116,6 @@ def _causal_mask(s: int, window: Optional[int]) -> jax.Array:
     return mask
 
 
-def _chunked_grouped_attention(
-    q: jax.Array,  # [B, S, H, hd]
-    k: jax.Array,  # [B, S, KV, hd]
-    v: jax.Array,
-    window: Optional[int],
-    scale: float,
-    chunk: int,
-) -> jax.Array:
-    """Query-chunked causal attention: peak score buffer is [.., chunk, S]
-    instead of [.., S, S] (prefill memory cap; keys stay resident)."""
-    b, s, h, hd = q.shape
-    nc = s // chunk
-    qc = q.reshape(b, nc, chunk, h, hd).transpose(1, 0, 2, 3, 4)
-    j = jnp.arange(s)
-
-    def one(args):
-        qi, ci = args
-        rows = ci * chunk + jnp.arange(chunk)
-        mask = j[None, :] <= rows[:, None]
-        if window is not None:
-            mask &= (rows[:, None] - j[None, :]) < window
-        return _grouped_attention(qi, k, v, mask, scale)
-
-    out = jax.lax.map(one, (qc, jnp.arange(nc)))
-    return out.transpose(1, 0, 2, 3, 4).reshape(b, s, h, hd)
-
-
 def gqa_apply(
     params: dict,
     cfg: ModelConfig,
@@ -154,14 +127,10 @@ def gqa_apply(
     """Full-sequence attention (train / prefill)."""
     b, s, _ = x.shape
     q, k, v = _qkv(params, cfg, x, positions)
-    if cfg.use_flash and mask_is_plain(cfg, s):
+    if cfg.use_flash:  # the kernel applies the causal + window mask itself
         from repro.kernels.flash_attention import ops as flash_ops
 
         out = flash_ops.flash_attention(q, k, v, window=cfg.window)
-    elif cfg.prefill_chunk and s > cfg.prefill_chunk and s % cfg.prefill_chunk == 0:
-        out = _chunked_grouped_attention(
-            q, k, v, cfg.window, cfg.head_dim ** -0.5, cfg.prefill_chunk
-        )
     else:
         mask = _causal_mask(s, cfg.window)
         out = _grouped_attention(q, k, v, mask, cfg.head_dim ** -0.5)
@@ -177,10 +146,6 @@ def gqa_apply(
             positions=pos_arr.at[:take].set(positions[-take:].astype(jnp.int32)),
         )
     return y, cache
-
-
-def mask_is_plain(cfg: ModelConfig, s: int) -> bool:
-    return True  # flash kernel handles causal + window masks itself
 
 
 def gqa_decode(
@@ -292,34 +257,18 @@ def _mla_full(params, cfg, x, positions, return_cache, cache_capacity):
     k_nope = linear(c_kv, params["w_uk"]).reshape(b, s, h, nope)
     v = linear(c_kv, params["w_uv"]).reshape(b, s, h, v_hd)
     scale = _mla_scale(cfg)
-
-    def _attend(qn, qr, rows):  # qn [B,C,H,nope], rows [C]
-        sc = (
-            jnp.einsum("bshn,bthn->bhst", qn, k_nope)
-            + jnp.einsum("bshr,btr->bhst", qr, k_rope)
-        ).astype(jnp.float32) * scale
-        j = jnp.arange(s)
-        mask = j[None, :] <= rows[:, None]
-        if cfg.window is not None:
-            mask &= (rows[:, None] - j[None, :]) < cfg.window
-        sc = jnp.where(mask[None, None], sc, NEG_INF)
-        probs = jax.nn.softmax(sc, axis=-1).astype(v.dtype)
-        return jnp.einsum("bhst,bthv->bshv", probs, v)
-
-    chunk = cfg.prefill_chunk
-    if chunk and s > chunk and s % chunk == 0:
-        nc = s // chunk
-        qn_c = q_nope.reshape(b, nc, chunk, h, nope).transpose(1, 0, 2, 3, 4)
-        qr_c = q_rope.reshape(b, nc, chunk, h, cfg.rope_head_dim).transpose(1, 0, 2, 3, 4)
-
-        def one(args):
-            qn, qr, ci = args
-            return _attend(qn, qr, ci * chunk + jnp.arange(chunk))
-
-        out = jax.lax.map(one, (qn_c, qr_c, jnp.arange(nc)))
-        out = out.transpose(1, 0, 2, 3, 4).reshape(b, s, -1)
-    else:
-        out = _attend(q_nope, q_rope, jnp.arange(s)).reshape(b, s, -1)
+    rows = jnp.arange(s)
+    sc = (
+        jnp.einsum("bshn,bthn->bhst", q_nope, k_nope)
+        + jnp.einsum("bshr,btr->bhst", q_rope, k_rope)
+    ).astype(jnp.float32) * scale
+    j = jnp.arange(s)
+    mask = j[None, :] <= rows[:, None]
+    if cfg.window is not None:
+        mask &= (rows[:, None] - j[None, :]) < cfg.window
+    sc = jnp.where(mask[None, None], sc, NEG_INF)
+    probs = jax.nn.softmax(sc, axis=-1).astype(v.dtype)
+    out = jnp.einsum("bhst,bthv->bshv", probs, v).reshape(b, s, -1)
     y = linear(out, params["wo"])
     cache = None
     if return_cache:

@@ -75,8 +75,7 @@ class ModelConfig:
     ssm_chunk: int = 128
     d_conv: int = 4
     ssm_split_proj: bool = False  # separate z/x/B/C/dt projections (and
-    # per-stream convs) so each output dim shards head-aligned over `model`
-    # instead of slicing one fused (misaligned) in_proj — see §Perf E4
+    # per-stream convs) instead of slicing one fused in_proj
 
     # --- hybrid ---
     attn_every: int = 0
@@ -88,9 +87,6 @@ class ModelConfig:
     # --- numerics / execution ---
     dtype: str = "float32"          # params & activations
     remat: bool = False             # checkpoint each block in train mode
-    remat_policy: str = "full"      # "full" | "dots" (save matmul outputs —
-                                    # backward skips recomputing them)
-    prefill_chunk: int = 0          # >0: chunk prefill queries (memory cap)
     unroll: bool = False            # python-loop layers instead of lax.scan
                                     # (exact HLO cost analysis; probes only)
     use_flash: bool = False         # route attention through Pallas kernel

@@ -294,13 +294,7 @@ def _run_trunk_full(
             return (h, aux_acc), cache_entries
 
         if cfg.remat:
-            if cfg.remat_policy == "dots":
-                body = jax.checkpoint(
-                    body,
-                    policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-                )
-            else:
-                body = jax.checkpoint(body)
+            body = jax.checkpoint(body)
         if cfg.unroll:
             ys = []
             carry = (x, aux_total)

@@ -45,8 +45,7 @@ def mamba_init(key: jax.Array, cfg: ModelConfig, dtype) -> dict:
         "out_proj": dense_init(ks[2], (di, d), dtype),
     }
     if cfg.ssm_split_proj:
-        # per-stream projections: z/x shard head-aligned over `model`,
-        # B/C/dt stay small; per-stream convs keep channel shards intact
+        # per-stream projections (z, x, B, C, dt) with per-stream convs
         return {
             **common,
             "in_z": dense_init(ks[0], (d, di), dtype),

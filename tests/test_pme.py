@@ -150,3 +150,52 @@ def test_neighbor_selection_counts_and_validity():
         )
     )
     assert a2[:, 3].sum() == 0
+
+
+@pytest.mark.parametrize("self_view", [False, True], ids=["no_self", "self"])
+@pytest.mark.parametrize("mask_mode", ["exact", "bernoulli"])
+@pytest.mark.parametrize("form", ["dense", "padded"])
+def test_exchange_forms_keep_algorithm2_invariants(form, mask_mode, self_view):
+    """Both pytree exchange forms keep Algorithm 2's invariants on a
+    two-leaf model: a receiver that selects no one keeps its own view bit
+    for bit (the self view where one is given), and every output
+    coordinate lies within the range of the selected senders' values and
+    the receiver's own."""
+    from repro.core.pame import PaMEConfig, make_topology_arrays
+    from repro.core.topology import build_topology
+
+    m = 6
+    topo = build_topology("erdos_renyi", m, p=0.6, seed=0)
+    arrs = make_topology_arrays(topo, PaMEConfig(nu=0.5))
+    rng = np.random.default_rng(5)
+    tree = {"a": jnp.asarray(rng.standard_normal((m, 3, 4)), jnp.float32),
+            "b": jnp.asarray(rng.standard_normal((m, 7)), jnp.float32)}
+    own = (jax.tree_util.tree_map(lambda x: x + 3.0, tree) if self_view
+           else tree)
+    comm = jnp.ones((m,), bool).at[1].set(False).at[4].set(False)
+    k_sel, k_mask = jax.random.split(jax.random.PRNGKey(7))
+    sel = np.asarray(pme.sample_neighbor_selection_padded(
+        k_sel, arrs.nbrs, arrs.valid, arrs.t, comm))
+    if form == "dense":
+        a = pme.sample_neighbor_selection(
+            k_sel, arrs.nbrs, arrs.valid, arrs.t, comm)
+        out = pme.pme_average_pytree(
+            k_mask, tree, a, 0.3, mode=mask_mode,
+            self_params=own if self_view else None)
+    else:
+        out = pme.pme_average_pytree_padded(
+            k_mask, tree, arrs.nbrs, jnp.asarray(sel), 0.3, mode=mask_mode,
+            pad=~arrs.valid, self_params=own if self_view else None)
+    nbrs = np.asarray(arrs.nbrs)
+    for name in tree:
+        w, v, o = (np.asarray(t[name]) for t in (tree, own, out))
+        for i in range(m):
+            senders = nbrs[i][sel[i]]
+            if not senders.size:
+                np.testing.assert_array_equal(o[i], v[i])
+                continue
+            seen = np.concatenate([w[senders], v[i][None]])
+            assert np.all(o[i] >= seen.min(axis=0) - 1e-5)
+            assert np.all(o[i] <= seen.max(axis=0) + 1e-5)
+            assert not np.array_equal(o[i], v[i])  # the exchange moved it
+    assert not sel[1].any() and not sel[4].any() and sel.any()

@@ -316,22 +316,21 @@ def test_realized_wire_bits_match_hand_count_gossip():
     assert hist["wire_bits_total"] == sum(expected)
 
 
-@pytest.mark.parametrize("exchange,value_bits", [("dense", 64),
-                                                 ("compressed_q8", 8)])
-def test_realized_wire_bits_match_hand_count_pame(exchange, value_bits):
+@pytest.mark.parametrize("mixing", ["sparse", "dense"])
+def test_realized_wire_bits_match_hand_count_pame(mixing):
     """PaME's realized accounting: per-step wire_bits == (number of
-    selected surviving sender->receiver messages) x message_bits(s, n,
-    value_bits), with the selection reproduced from the same PRNG stream
-    and the int8 wire format honored for exchange="compressed_q8"."""
+    selected surviving sender->receiver messages) x message_bits(s, n),
+    with the selection reproduced from the same PRNG stream — through the
+    padded selection (sparse mixing) or the [m, m] selection matrix
+    (dense mixing)."""
     m, n = 10, 30
     seed = 0
     topo = build_topology("erdos_renyi", m, p=0.5, seed=2)
     scen = Scenario(name="t", edge_drop=0.25, churn=0.25, seed=7)
-    cfg = ALG.PaMEHp(nu=0.5, p=0.3, gamma=1.01, sigma0=8.0,
-                     exchange=exchange)
+    cfg = ALG.PaMEHp(nu=0.5, p=0.3, gamma=1.01, sigma0=8.0)
     batch, grad_fn = _linreg(m, n)
     bound = ALG.get_algorithm("pame").bind(
-        grad_fn, topo, cfg, seed=seed, scenario=scen
+        grad_fn, topo, cfg, mixing=mixing, seed=seed, scenario=scen
     )
     steps = 8
     key = jax.random.PRNGKey(0)
@@ -341,13 +340,15 @@ def test_realized_wire_bits_match_hand_count_pame(exchange, value_bits):
     )
     arrs = make_topology_arrays(topo, cfg, seed=seed)
     s = max(1, int(round(cfg.p * n)))
-    per_msg = pme.message_bits(s, n, value_bits)
+    per_msg = pme.message_bits(s, n)
+    sample = (pme.sample_neighbor_selection_padded if mixing == "sparse"
+              else pme.sample_neighbor_selection)
     expected = []
     for k in range(steps):
         r = realize(scen, bound.scen_arrays, k)
         k_sel = jax.random.fold_in(key, k * 3)
         comm = ((jnp.asarray(k) % arrs.kappa) == 0) & r.participating
-        sel = pme.sample_neighbor_selection_padded(
+        sel = sample(
             k_sel, arrs.nbrs, arrs.valid, arrs.t, comm, survivors=r.edge_alive
         )
         expected.append(float(int(sel.sum()) * per_msg))

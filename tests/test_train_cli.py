@@ -1,6 +1,7 @@
 """Launcher regressions: seed-dependent batch stream, realized wire-bit
-accounting across checkpoint resume, and the tree-partitioned end-to-end
-smoke on a >=1M-param registered model.
+accounting across checkpoint resume, the tree-partitioned end-to-end
+smoke on a >=1M-param registered model, and PaME rounds for the hybrid
+and MoE/MLA families.
 """
 import json
 import os
@@ -129,3 +130,17 @@ def test_partition_tree_trains_stablelm_smoke(capsys):
     assert losses and all(np.isfinite(losses))
     assert losses[-1] < losses[0]
     assert "[train] done" in out
+
+
+# ---------------------------------------------------------------------------
+# a PaME round through the CLI for the hybrid and the MoE/MLA families
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("arch", ["zamba2-1.2b", "deepseek-v2-lite-16b"])
+def test_pame_round_runs_for_arch_family(arch, capsys):
+    out = train_mod.main(["--arch", arch, "--variant", "smoke",
+                          "--steps", "2", "--chunk", "2", "--nodes", "4",
+                          "--batch", "2", "--seq", "32", "--mixing", "dense"])
+    log = capsys.readouterr().out
+    assert "algo=pame mixing=dense" in log and "[train] done" in log
+    assert out["loss"].shape == (2,)
+    assert np.all(np.isfinite(out["loss"]))

@@ -207,8 +207,6 @@ def test_config_validation():
         PaMEConfig(partition="columns")
     with pytest.raises(ValueError, match="p_leaf"):
         PaMEConfig(p_leaf=(0.5, 0.5))  # flat partition
-    with pytest.raises(NotImplementedError, match="dense"):
-        PaMEConfig(partition="tree", exchange="compressed")
     params0, grad_fn = _problem()
     topo = build_topology("erdos_renyi", 4, p=0.9, seed=0)
     cfg = PaMEConfig(partition="tree", p_leaf=(0.5, 0.5, 0.5))  # 3 != 2 leaves
